@@ -54,7 +54,7 @@ def set_activation_batch_axes(axes: Sequence[str]) -> None:
 
 
 def _axis_sizes(mesh) -> dict:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(mesh.shape)
 
 
 def _maybe(axis, dim: int, mesh):
@@ -162,10 +162,21 @@ def replicated(mesh) -> NamedSharding:
 
 
 def _ctx_mesh() -> Optional[Any]:
-    """The mesh installed by the enclosing `with mesh:` block, if any."""
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
+    """The (abstract) mesh installed by an enclosing ``jax.set_mesh``."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
+
+
+def on_every_device(fn):
+    """``fn`` run whole on every device of the context mesh, on replicated
+    operands (a ``shard_map`` with replicated specs); ``fn`` itself
+    off-mesh. XLA cannot partition a Pallas (Mosaic) kernel, so a kernel
+    call inside a jit over the mesh goes through this."""
+    mesh = _ctx_mesh()
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def constrain_act(x):
@@ -173,8 +184,7 @@ def constrain_act(x):
     mesh = _ctx_mesh()
     if mesh is None:
         return x
-    spec = batch_spec(x.shape, mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, batch_spec(x.shape, mesh))
 
 
 def constrain_heads(x):
@@ -191,7 +201,7 @@ def constrain_heads(x):
         spec = P(ba, None, None, "model")
     else:
         spec = P(ba, None, None, None)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # --------------------------------------------------------------------------

@@ -21,6 +21,27 @@ DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 128
 DEFAULT_BLOCK = DEFAULT_BLOCK_Q  # back-compat alias
 
+# Mosaic's default scoped-VMEM limit on v5e.
+VMEM_LIMIT = 16 * 1024 * 1024
+
+
+def _vmem_bytes(hq: int, hkv: int, d: int, block_q: int, block_k: int,
+                itemsize: int) -> int:
+    """Upper estimate of what one grid step of the kernel keeps in VMEM.
+
+    The last dim of every tile pads to whole 128-lane rows (D=64 costs
+    128, the (.., 1) softmax stats cost 128). Double-buffered q, out, k
+    and v tiles; fp32 acc, m and l scratch; and the body's fp32
+    temporaries (q and k/v casts, logits, probabilities, mask).
+    """
+    lanes = -(-d // 128) * 128
+    gbq = hq // hkv * block_q
+    io = 2 * (2 * hq * block_q + 2 * hkv * block_k) * lanes * itemsize
+    scratch = hkv * gbq * (lanes + 2 * 128) * 4
+    temps = hkv * (gbq * lanes + 3 * gbq * max(block_k, 128)
+                   + 2 * block_k * lanes) * 4
+    return io + scratch + temps
+
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -39,6 +60,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     b, s, hq, d = q.shape
     block_q = min(block_q, max(8, 1 << (s - 1).bit_length()))
     block_k = min(block_k, block_q)
+    # wide head groups or fp32 inputs: shorter q tiles until a step fits
+    while block_q > block_k and _vmem_bytes(
+            hq, k.shape[2], d, block_q, block_k,
+            q.dtype.itemsize) > VMEM_LIMIT:
+        block_q //= 2
     pad = (-s) % max(block_q, block_k)
     qt = jnp.moveaxis(q, 2, 1)
     kt = jnp.moveaxis(k, 2, 1)

@@ -6,8 +6,10 @@ Layout/tiling rationale (TPU v5e):
     the wrapper pads/reshapes arbitrary tensors into this layout;
   * grid over row-tiles; BLOCK_R is chosen in ops.py per kernel from the
     actual resident operand dtypes so VMEM stays under budget;
-  * (lo, scale) arrive as a (1, 2) operand (global-scale quantization —
-    min/max is a cheap jnp reduction outside the kernel);
+  * (lo, scale) arrive as a whole (1, 2) or (n_buckets, 2) operand in SMEM
+    (scalar memory: a VMEM block must be (8, 128)-aligned, and a kernel
+    reads these as scalars anyway); per-bucket stats are written to a
+    whole-array SMEM output the same way;
   * pure VPU elementwise work, no MXU; stochastic rounding compares the
     uniform draw against the fractional part.
 
@@ -33,6 +35,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# whole array resident in scalar memory for the entire grid
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _quantize(x, u, lo, scale, levels: int):
@@ -83,7 +89,7 @@ def qdq(x: jnp.ndarray, u: jnp.ndarray, params: jnp.ndarray, *, bits: int,
         kernel,
         grid=(pl.cdiv(r, block_r),),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
+            _SMEM,
             pl.BlockSpec((block_r, c), lambda i: (i, 0)),
             pl.BlockSpec((block_r, c), lambda i: (i, 0)),
         ],
@@ -106,8 +112,7 @@ def encode_packed(x3: jnp.ndarray, u3: jnp.ndarray, params: jnp.ndarray, *,
     return pl.pallas_call(
         kernel,
         grid=(pl.cdiv(r, block_r),),
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0)), seg_spec,
-                  seg_spec],
+        in_specs=[_SMEM, seg_spec, seg_spec],
         out_specs=pl.BlockSpec((block_r, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.uint8),
         interpret=interpret,
@@ -124,7 +129,7 @@ def decode_packed(payload: jnp.ndarray, params: jnp.ndarray, *, bits: int,
         kernel,
         grid=(pack, pl.cdiv(r, block_r)),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda k, i: (0, 0)),
+            _SMEM,
             pl.BlockSpec((block_r, c), lambda k, i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_r, c), lambda k, i: (k, i, 0)),
@@ -146,9 +151,9 @@ def decode_packed(payload: jnp.ndarray, params: jnp.ndarray, *, bits: int,
 
 def _qdq_bucketed_kernel(params_ref, x_ref, u_ref, o_ref, *, levels: int):
     """x_ref, u_ref, o_ref: (1, pack, BLOCK_R, C); params_ref is the FULL
-    (n_buckets, 2) params array, hoisted into VMEM once for the whole grid
-    (constant index map — no per-step refetch of the (lo, scale) row; the
-    kernel picks its bucket's row by program id)."""
+    (n_buckets, 2) params array, resident in SMEM for the whole grid (no
+    per-step refetch of the (lo, scale) row; the kernel picks its bucket's
+    row by program id)."""
     bi = pl.program_id(0)
     lo = params_ref[bi, 0]
     scale = params_ref[bi, 1]
@@ -160,7 +165,7 @@ def _encode_packed_bucketed_kernel(params_ref, x_ref, u_ref, o_ref, *,
                                    bits: int):
     """x_ref, u_ref: (1, pack, BLOCK_R, C) — one bucket's row tile, all
     segments; o_ref: (1, BLOCK_R, C) packed payload tile; params_ref: the
-    full hoisted (n_buckets, 2) array (see _qdq_bucketed_kernel)."""
+    full SMEM-resident (n_buckets, 2) array (see _qdq_bucketed_kernel)."""
     pack = 8 // bits
     levels = (1 << bits) - 1
     bi = pl.program_id(0)
@@ -193,7 +198,7 @@ def qdq_bucketed(x4: jnp.ndarray, u4: jnp.ndarray, params: jnp.ndarray, *,
     return pl.pallas_call(
         kernel,
         grid=(b, pl.cdiv(r, block_r)),
-        in_specs=[pl.BlockSpec((b, 2), lambda bi, i: (0, 0)), seg, seg],
+        in_specs=[_SMEM, seg, seg],
         out_specs=seg,
         out_shape=jax.ShapeDtypeStruct((b, pack, r, c), x4.dtype),
         interpret=interpret,
@@ -211,7 +216,7 @@ def encode_packed_bucketed(x4: jnp.ndarray, u4: jnp.ndarray,
     return pl.pallas_call(
         kernel,
         grid=(b, pl.cdiv(r, block_r)),
-        in_specs=[pl.BlockSpec((b, 2), lambda bi, i: (0, 0)), seg, seg],
+        in_specs=[_SMEM, seg, seg],
         out_specs=pl.BlockSpec((1, block_r, c), lambda bi, i: (bi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, r, c), jnp.uint8),
         interpret=interpret,
@@ -219,12 +224,13 @@ def encode_packed_bucketed(x4: jnp.ndarray, u4: jnp.ndarray,
 
 
 def _minmax_bucketed_kernel(x_ref, o_ref, *, n_rows: int, block_r: int):
-    """x_ref: (1, BLOCK_R, C) one bucket's row tile; o_ref: (1, 2) the
-    bucket's [lo, hi], accumulated across the (sequential) row-tile grid
-    dimension — the output block revisits for every row tile of the same
-    bucket, so this is a single-read fused min+max reduction. Rows past
+    """x_ref: (1, BLOCK_R, C) one bucket's row tile; o_ref: the whole
+    (B, 2) SMEM output, whose row bi holds the bucket's [lo, hi],
+    accumulated across the (sequential) row-tile grid dimension — a
+    single-read fused min+max reduction. Rows past
     n_rows (grid padding of the last tile) are masked out of the
     reduction: padded values must never touch the bucket's range."""
+    bi = pl.program_id(0)
     i = pl.program_id(1)
     x = x_ref[0]
     row = i * block_r + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
@@ -234,13 +240,13 @@ def _minmax_bucketed_kernel(x_ref, o_ref, *, n_rows: int, block_r: int):
 
     @pl.when(i == 0)
     def _init():
-        o_ref[0, 0] = tile_lo
-        o_ref[0, 1] = tile_hi
+        o_ref[bi, 0] = tile_lo
+        o_ref[bi, 1] = tile_hi
 
     @pl.when(i > 0)
     def _acc():
-        o_ref[0, 0] = jnp.minimum(o_ref[0, 0], tile_lo)
-        o_ref[0, 1] = jnp.maximum(o_ref[0, 1], tile_hi)
+        o_ref[bi, 0] = jnp.minimum(o_ref[bi, 0], tile_lo)
+        o_ref[bi, 1] = jnp.maximum(o_ref[bi, 1], tile_hi)
 
 
 def minmax_bucketed(x3: jnp.ndarray, *, block_r: int,
@@ -258,7 +264,7 @@ def minmax_bucketed(x3: jnp.ndarray, *, block_r: int,
         kernel,
         grid=(b, pl.cdiv(r, block_r)),
         in_specs=[pl.BlockSpec((1, block_r, c), lambda bi, i: (bi, i, 0))],
-        out_specs=pl.BlockSpec((1, 2), lambda bi, i: (bi, 0)),
+        out_specs=_SMEM,
         out_shape=jax.ShapeDtypeStruct((b, 2), jnp.float32),
         interpret=interpret,
     )(x3)
@@ -270,13 +276,14 @@ def minmax_bucketed(x3: jnp.ndarray, *, block_r: int,
 # temporaries between them (the decoded message, then the sum); here the
 # grid runs TWO phases over each bucket — steps [0, n_tiles) decode the
 # payload tile, add the local tile, and min/max-accumulate the new bucket
-# range into a (1, 2) VMEM scratch; steps [n_tiles, 2*n_tiles) recompute
+# range into a (2,) SMEM scratch; steps [n_tiles, 2*n_tiles) recompute
 # the same decode+add (recompute beats materializing: the fp32 sum never
 # exists outside VMEM) and quantize/bit-pack it with the scratch-held
-# (lo, scale). The params output is written at the last stats step; the
-# payload output's index map parks all stats steps on block 0, so every
-# output block's revisits stay consecutive (TPU flush rule) and its final
-# visit is the encode step that writes it. Bit-identical to the sequential
+# (lo, scale). The params output (the whole (B, 2) array in SMEM) gets
+# row bi at the bucket's last stats step; the payload output's index map
+# parks all stats steps on block 0, so every output block's revisits stay
+# consecutive (TPU flush rule) and its final visit is the encode step that
+# writes it. Bit-identical to the sequential
 # decode -> add -> minmax -> encode chain: same decoded values, same adds,
 # exact min/max, same _quantize math, same (externally drawn) uniforms.
 # ---------------------------------------------------------------------------
@@ -286,11 +293,12 @@ def _decode_add_encode_bucketed_kernel(params_ref, pay_ref, x_ref, u_ref,
                                        out_ref, pout_ref, mm_scr, *,
                                        bits: int, n_tiles: int, n_rows: int,
                                        block_r: int):
-    """params_ref: full hoisted (B, 2) [lo, scale] of the INCOMING message;
-    pay_ref: (1, BLOCK_R, C) incoming payload tile; x_ref, u_ref: (1, pack,
-    BLOCK_R, C) local-addend / uniform tiles; out_ref: (1, BLOCK_R, C)
-    re-encoded payload tile; pout_ref: (1, 2) this bucket's new params;
-    mm_scr: (1, 2) VMEM carry — [lo, hi] during stats, [lo, scale] after."""
+    """params_ref: full SMEM-resident (B, 2) [lo, scale] of the INCOMING
+    message; pay_ref: (1, BLOCK_R, C) incoming payload tile; x_ref, u_ref:
+    (1, pack, BLOCK_R, C) local-addend / uniform tiles; out_ref: (1,
+    BLOCK_R, C) re-encoded payload tile; pout_ref: whole (B, 2) SMEM output, row bi
+    this bucket's new params; mm_scr: (2,) SMEM carry — [lo, hi] during
+    stats, [lo, scale] after."""
     bi = pl.program_id(0)
     i = pl.program_id(1)
     pack = 8 // bits
@@ -308,8 +316,8 @@ def _decode_add_encode_bucketed_kernel(params_ref, pay_ref, x_ref, u_ref,
 
     @pl.when(i == 0)
     def _init():
-        mm_scr[0, 0] = jnp.float32(jnp.inf)
-        mm_scr[0, 1] = jnp.float32(-jnp.inf)
+        mm_scr[0] = jnp.float32(jnp.inf)
+        mm_scr[1] = jnp.float32(-jnp.inf)
 
     @pl.when(i < n_tiles)
     def _stats():
@@ -322,22 +330,22 @@ def _decode_add_encode_bucketed_kernel(params_ref, pay_ref, x_ref, u_ref,
         for s in summed:
             lo_t = jnp.minimum(lo_t, jnp.min(jnp.where(valid, s, jnp.inf)))
             hi_t = jnp.maximum(hi_t, jnp.max(jnp.where(valid, s, -jnp.inf)))
-        mm_scr[0, 0] = jnp.minimum(mm_scr[0, 0], lo_t)
-        mm_scr[0, 1] = jnp.maximum(mm_scr[0, 1], hi_t)
+        mm_scr[0] = jnp.minimum(mm_scr[0], lo_t)
+        mm_scr[1] = jnp.maximum(mm_scr[1], hi_t)
 
     @pl.when(i == n_tiles - 1)
     def _finalize_params():
-        lo = mm_scr[0, 0]
-        hi = mm_scr[0, 1]
+        lo = mm_scr[0]
+        hi = mm_scr[1]
         scale = jnp.where(hi > lo, (hi - lo) / levels, 1.0)
-        pout_ref[0, 0] = lo
-        pout_ref[0, 1] = scale
-        mm_scr[0, 1] = scale          # phase 2 reads [lo, scale]
+        pout_ref[bi, 0] = lo
+        pout_ref[bi, 1] = scale
+        mm_scr[1] = scale             # phase 2 reads [lo, scale]
 
     @pl.when(i >= n_tiles)
     def _encode():
-        lo = mm_scr[0, 0]
-        scale = mm_scr[0, 1]
+        lo = mm_scr[0]
+        scale = mm_scr[1]
         acc = None
         for k in range(pack):
             q = _quantize(summed[k], u_ref[0, k], lo, scale, levels)
@@ -367,7 +375,7 @@ def decode_add_encode_bucketed(payload: jnp.ndarray, params: jnp.ndarray,
         kernel,
         grid=(b, 2 * n_tiles),
         in_specs=[
-            pl.BlockSpec((b, 2), lambda bi, i: (0, 0)),   # hoisted params
+            _SMEM,
             pl.BlockSpec((1, block_r, c),
                          lambda bi, i, nt=n_tiles:
                          (bi, jax.lax.rem(i, nt), 0)),
@@ -380,13 +388,13 @@ def decode_add_encode_bucketed(payload: jnp.ndarray, params: jnp.ndarray,
             pl.BlockSpec((1, block_r, c),
                          lambda bi, i, nt=n_tiles:
                          (bi, jnp.where(i < nt, 0, i - nt), 0)),
-            pl.BlockSpec((1, 2), lambda bi, i: (bi, 0)),
+            _SMEM,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, r, c), jnp.uint8),
             jax.ShapeDtypeStruct((b, 2), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, 2), jnp.float32)],
+        scratch_shapes=[pltpu.SMEM((2,), jnp.float32)],
         interpret=interpret,
     )(params, payload, x4, u4)
 
@@ -402,7 +410,7 @@ def decode_packed_bucketed(payload: jnp.ndarray, params: jnp.ndarray, *,
         kernel,
         grid=(pack, b, pl.cdiv(r, block_r)),
         in_specs=[
-            pl.BlockSpec((b, 2), lambda k, bi, i: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, block_r, c), lambda k, bi, i: (bi, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_r, c),

@@ -34,7 +34,9 @@ from repro.obs import flight as obs_flight
 from repro.kernels.quant import kernel, ref
 
 LANES = 512
-VMEM_BUDGET = 8 * 1024 * 1024   # conservative half of ~16MB usable
+# Three quarters of the 16 MiB scoped-VMEM limit Mosaic applies by default
+# on v5e; the rest is headroom for the compiler's own scratch.
+VMEM_BUDGET = 12 * 1024 * 1024
 
 # Fused flat-buffer tier: elements per quantization bucket (4Mi elements =
 # 16 MiB fp32 per bucket -> a 100M-param gradient is ~25-31 (lo, scale)
@@ -55,16 +57,23 @@ def _use_pallas(backend: str) -> bool:
     return backend == "pallas"
 
 
-def _block_r(c: int, bytes_per_out_row_elem: int) -> int:
-    """Rows per grid step such that all resident tiles fit VMEM_BUDGET.
+def _block_r(c: int, io_bytes: int, f32_elems: int) -> int:
+    """Rows per grid step such that what one step keeps in VMEM fits
+    VMEM_BUDGET.
 
-    `bytes_per_out_row_elem` sums, over every operand tile resident during
-    one grid step, the bytes that correspond to ONE element-column of one
-    output row (per-kernel: qdq has 3 fp32 tiles = 12; packed encode has
-    pack fp32 x-segments + pack fp32 u-segments + 1 uint8 out = 8*pack+1;
-    decode has 1 uint8 in + 1 fp32 out = 5).
+    Per element-column of one block row, a step holds every blocked
+    in/out tile twice (Pallas double-buffers them: ``2 * io_bytes``) plus
+    the body's temporaries: about two fp32 values per fp32 element of the
+    tile (``f32_elems`` of them per row element) and one int32 packing
+    accumulator. ``io_bytes`` sums the tiles' bytes per row element: qdq
+    has x, u and out fp32 (12, times pack for the bucketed segment view);
+    a packed encode has pack fp32 x-segments, pack fp32 u-segments and one
+    uint8 out (8 * pack + 1); decode has one uint8 in and one fp32 out (5).
+    Checked against the v5e compiler at rq8/rq4/rq2 by
+    tests/test_tpu_compile.py.
     """
-    rows = VMEM_BUDGET // (bytes_per_out_row_elem * c)
+    per_elem = 2 * io_bytes + 8 * f32_elems + 4
+    rows = VMEM_BUDGET // (per_elem * c)
     rows = max(8, min(1024, rows))
     return int(rows) & ~7 or 8   # multiple of 8 sublanes
 
@@ -96,7 +105,7 @@ def quantize_dequantize(x: jnp.ndarray, key: jax.Array, *, bits: int = 8,
     u = jax.random.uniform(key, x2d.shape, jnp.float32)
     if _use_pallas(backend):
         out = kernel.qdq(x2d, u, params, bits=bits,
-                         block_r=_block_r(x2d.shape[1], 3 * 4),
+                         block_r=_block_r(x2d.shape[1], 12, 1),
                          interpret=_interpret())
     else:
         # direct qdq: skips the encode -> uint8 -> decode round trip (a
@@ -122,7 +131,7 @@ def encode(x: jnp.ndarray, key: jax.Array, *, bits: int = 8,
     if _use_pallas(backend):
         payload = kernel.encode_packed(
             x3, u, params, bits=bits,
-            block_r=_block_r(x3.shape[2], 8 * pack + 1),
+            block_r=_block_r(x3.shape[2], 8 * pack + 1, pack),
             interpret=_interpret())
     else:
         payload = ref.encode_packed(x3, u, params[0, 0], params[0, 1],
@@ -138,7 +147,7 @@ def decode(payload: jnp.ndarray, params: jnp.ndarray, *, shape: tuple,
     if _use_pallas(backend):
         out3 = kernel.decode_packed(
             payload, params, bits=bits, out_dtype=jnp.float32,
-            block_r=_block_r(payload.shape[1], 1 + 4),
+            block_r=_block_r(payload.shape[1], 1 + 4, 1),
             interpret=_interpret())
     else:
         out3 = ref.decode_packed(payload, params[0, 0], params[0, 1],
@@ -210,7 +219,7 @@ def bucket_params(x2: jnp.ndarray, *, bits: int,
         nb, cap = x2.shape
         mm = kernel.minmax_bucketed(
             x2.reshape(nb, cap // LANES, LANES),
-            block_r=_block_r(LANES, 4), interpret=_interpret())
+            block_r=_block_r(LANES, 4, 1), interpret=_interpret())
         lo, hi = mm[:, 0], mm[:, 1]
     else:
         lo, hi = ref.minmax_bucketed(x2)
@@ -346,11 +355,11 @@ def _qdq_flat_impl(flat: jnp.ndarray, key: jax.Array, *, bits: int = 8,
         if nb > 1:
             head = kernel.qdq_bucketed(
                 x4, u4, params[:nb - 1], bits=bits,
-                block_r=_block_r(LANES, 12 * pack),
+                block_r=_block_r(LANES, 12 * pack, pack),
                 interpret=_interpret()).reshape(-1)
         tl = kernel.qdq(x3.reshape(pack * rt, LANES),
                         u3.reshape(pack * rt, LANES), params[nb - 1:nb],
-                        bits=bits, block_r=_block_r(LANES, 3 * 4),
+                        bits=bits, block_r=_block_r(LANES, 12, 1),
                         interpret=_interpret())
     else:
         if nb > 1:
@@ -452,11 +461,12 @@ def encode_flat(flat: jnp.ndarray, key: jax.Array, *, bits: int = 8,
         if nb > 1:
             head = kernel.encode_packed_bucketed(
                 x4, u4, params[:nb - 1], bits=bits,
-                block_r=_block_r(LANES, 8 * pack + 1),
+                block_r=_block_r(LANES, 8 * pack + 1, pack),
                 interpret=_interpret()).reshape(-1, LANES)
         tl = kernel.encode_packed(
             x3, u3, params[nb - 1:nb], bits=bits,
-            block_r=_block_r(LANES, 8 * pack + 1), interpret=_interpret())
+            block_r=_block_r(LANES, 8 * pack + 1, pack),
+            interpret=_interpret())
     else:
         if nb > 1:
             head = ref.encode_packed_bucketed(
@@ -557,11 +567,11 @@ def decode_flat(payload: jnp.ndarray, params: jnp.ndarray, *, total: int,
             head = kernel.decode_packed_bucketed(
                 payload[:head_rows].reshape(nb - 1, rows_b, LANES),
                 params[:nb - 1], bits=bits, out_dtype=jnp.float32,
-                block_r=_block_r(LANES, 1 + 4),
+                block_r=_block_r(LANES, 1 + 4, 1),
                 interpret=_interpret()).reshape(-1)
         tl = kernel.decode_packed(
             payload[head_rows:], params[nb - 1:nb], bits=bits,
-            out_dtype=jnp.float32, block_r=_block_r(LANES, 1 + 4),
+            out_dtype=jnp.float32, block_r=_block_r(LANES, 1 + 4, 1),
             interpret=_interpret())
     else:
         if nb > 1:
@@ -640,7 +650,7 @@ def decode_add_encode_flat(payload: jnp.ndarray, params: jnp.ndarray,
         if use_pallas:
             head, head_p = kernel.decode_add_encode_bucketed(
                 pay4, params[:nb - 1], x4, u4, bits=bits,
-                block_r=_block_r(LANES, 8 * pack + 2),
+                block_r=_block_r(LANES, 8 * pack + 2, pack),
                 interpret=_interpret())
         else:
             head, head_p = _dae_ref(pay4, params[:nb - 1], x4, u4,
@@ -654,7 +664,8 @@ def decode_add_encode_flat(payload: jnp.ndarray, params: jnp.ndarray,
     if use_pallas:
         tl, tl_p = kernel.decode_add_encode_bucketed(
             pay3, params[nb - 1:nb], x3, u3, bits=bits,
-            block_r=_block_r(LANES, 8 * pack + 2), interpret=_interpret())
+            block_r=_block_r(LANES, 8 * pack + 2, pack),
+            interpret=_interpret())
     else:
         tl, tl_p = _dae_ref(pay3, params[nb - 1:nb], x3, u3, bits=bits)
     out_payload = _write_head_tail(head, tl.reshape(rt, LANES),

@@ -39,9 +39,16 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)           # (K,)
+    u = u_ref[0].astype(jnp.float32)           # (1, K)
 
-    cum = jnp.cumsum(lw, axis=0)               # (C, K) inclusive
+    # Mosaic lowers neither cumsum nor row indexing: the chunk's prefix
+    # sums and totals are small matmuls (HIGHEST keeps them fp32 sums)
+    hp = jax.lax.Precision.HIGHEST
+    t_pos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_pos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (s_pos <= t_pos).astype(jnp.float32)
+    cum = jax.lax.dot(tril, lw, precision=hp)   # (C, K) inclusive
+    tot = jax.lax.dot(jnp.ones_like(tril), lw, precision=hp)  # rows = total
     state = state_scr[...]                     # (K, K)
 
     # inter-chunk: q_t reads the chunk-entry state with decay prod_{s<t} w
@@ -51,21 +58,20 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref,
     # intra-chunk pairwise (strict lower triangle)
     kd = k * jnp.exp(-cum)
     att = jax.lax.dot_general(q_in, kd, (((1,), (1,)), ((), ())))  # (C, C)
-    t_pos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_pos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(s_pos < t_pos, att, 0.0)
     out_intra = jax.lax.dot(att, v)
 
     # current-token bonus
-    bonus = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)
     out_bonus = bonus * v
 
     o_ref[0, 0] = (out_inter + out_intra + out_bonus).astype(o_ref.dtype)
 
-    # state carry
-    total = cum[-1]                            # (K,)
-    k_carry = k * jnp.exp(total[None, :] - cum)
-    new_state = (jnp.exp(total)[:, None] * state
+    # state carry: S[i, j] decays by exp(total[i])
+    k_carry = k * jnp.exp(tot - cum)
+    decay = jax.lax.dot_general(lw, jnp.ones_like(lw),
+                                (((0,), (0,)), ((), ())), precision=hp)
+    new_state = (jnp.exp(decay) * state
                  + jax.lax.dot_general(k_carry, v, (((0,), (0,)), ((), ()))))
     state_scr[...] = new_state
 
@@ -80,13 +86,16 @@ def wkv6_bhsk(r, k, v, log_w, u, *, chunk: int, interpret: bool):
     assert s % chunk == 0, f"S={s} must be a multiple of chunk={chunk}"
     n_chunks = s // chunk
     kernel = functools.partial(_wkv_kernel, chunk=chunk, n_chunks=n_chunks)
+    # (H, 1, K): a (1, K) block then spans the array's last two dims, as
+    # the TPU lowering requires of a block that is not (8, 128)-aligned
+    u3 = u.reshape(h, 1, dk)
     seq_spec = pl.BlockSpec((1, 1, chunk, dk),
                             lambda b_, h_, c: (b_, h_, c, 0))
     out, state = pl.pallas_call(
         kernel,
         grid=(b, h, n_chunks),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, dk), lambda b_, h_, c: (h_, 0))],
+                  pl.BlockSpec((1, 1, dk), lambda b_, h_, c: (h_, 0, 0))],
         out_specs=[seq_spec,
                    pl.BlockSpec((1, 1, dk, dk),
                                 lambda b_, h_, c: (b_, h_, 0, 0))],
@@ -94,5 +103,5 @@ def wkv6_bhsk(r, k, v, log_w, u, *, chunk: int, interpret: bool):
                    jax.ShapeDtypeStruct((b, h, dk, dk), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dk, dk), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, log_w, u)
+    )(r, k, v, log_w, u3)
     return out, state

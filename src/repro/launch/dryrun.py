@@ -141,7 +141,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
     sharding.set_activation_batch_axes(
         ("pod", "data") if multi_pod else ("data",))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             fn = steps.make_train_step(cfg, spec["optimizer"],
                                        spec["step_cfg"])

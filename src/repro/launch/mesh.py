@@ -11,21 +11,30 @@ sets --xla_force_host_platform_device_count=512 before first jax use.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple, axes: tuple, *, devices=None):
+    """The repo's one mesh constructor: every axis is ``AxisType.Auto``.
+
+    ``jax.make_mesh`` alone makes Explicit axes (jax 0.9), under which
+    ``with_sharding_constraint`` and gathers over sharded tables are
+    refused; the sharding rules here (dist/sharding.py) pin layouts by
+    constraint and let XLA propagate the rest, which is the Auto model.
+    Install the mesh with ``jax.set_mesh(mesh)``; code inside jit reads it
+    back through ``jax.sharding.get_abstract_mesh()``. ``devices`` defaults
+    to ``jax.devices()``; a described (unattached) topology's devices give
+    a mesh to compile against.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(n: int | None = None, *, axes=("data",)):
-    """Small mesh over the real host devices (examples / integration tests)."""
-    n = n if n is not None else len(jax.devices())
-    import numpy as np
-    if len(axes) == 1:
-        return jax.make_mesh((n,), axes)
-    raise ValueError("host mesh supports a single axis")
+    return make_mesh(shape, axes)
 
 
 # Hardware constants for the roofline model (TPU v5e).

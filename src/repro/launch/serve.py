@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 
 from repro import serve
+from repro.launch import compile_cache
 
 
 def build_config(args) -> serve.ServeConfig:
@@ -51,6 +52,7 @@ def main(argv=None) -> serve.ServeResult:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compile_cache.use_compile_cache()
     result = serve.run(build_config(args))
     print(serve.format_result(result))
     return result

@@ -15,7 +15,9 @@ the available devices.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +26,26 @@ from repro import configs
 from repro.checkpoint import latest_checkpoint, load_state, save_state
 from repro.data.pipeline import SyntheticLM
 from repro.dist import sharding
+from repro.launch import compile_cache
+from repro.launch import mesh as mesh_lib
 from repro.optim import cosine_schedule, make_optimizer
 from repro.train import steps
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """What ``main`` ran: the final state, the logged ``(step, loss)``
+    pairs, the compiled step, and the last batch it was fed."""
+
+    state: dict
+    losses: list
+    compiled_step: Any
+    batch: dict
+
+
+def main(argv=None, *, devices=None) -> TrainRun:
+    """Train from CLI flags; ``devices`` (default: all of
+    ``jax.devices()``) are the data-parallel mesh."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="repro-100m")
     ap.add_argument("--steps", type=int, default=200)
@@ -49,11 +66,13 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compile_cache.use_compile_cache()
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    devices = list(jax.devices() if devices is None else devices)
+    n_dev = len(devices)
+    mesh = mesh_lib.make_mesh((n_dev, 1), ("data", "model"), devices=devices)
     sharding.set_activation_batch_axes(("data",))
     print(f"[train] arch={cfg.arch_id} params~{cfg.param_count()/1e6:.1f}M "
           f"devices={n_dev} batch={args.batch} seq={args.seq}")
@@ -76,19 +95,29 @@ def main(argv=None):
 
     data = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq + 1,
                        batch=args.batch, seed=args.seed)
-    with mesh:
-        state_sh = jax.tree_util.tree_map(
-            lambda _: sharding.replicated(mesh), jax.eval_shape(lambda: state))
+    losses = []
+    compiled = batch = None
+    with jax.set_mesh(mesh):
+        # data parallel: state replicated on every device, batch split
+        # over 'data'; the step keeps the state replicated
+        rep = sharding.replicated(mesh)
+        state = jax.device_put(state, rep)
         train_step = jax.jit(steps.make_train_step(cfg, opt, scfg),
-                             donate_argnums=(0,))
+                             out_shardings=(rep, rep), donate_argnums=(0,))
         t0 = time.time()
         for t in range(start, args.steps):
             batch = data.batch_at(t)
             batch = jax.device_put(
                 batch, sharding.batch_shardings(batch, mesh))
-            state, metrics = train_step(state, batch)
+            if compiled is None:
+                tc = time.time()
+                compiled = train_step.lower(state, batch).compile()
+                print(f"[train] step compiled in {time.time() - tc:.1f}s")
+                t0 = time.time()
+            state, metrics = compiled(state, batch)
             if t % args.log_every == 0 or t == args.steps - 1:
                 loss = float(metrics["loss"])
+                losses.append((t, loss))
                 dt = time.time() - t0
                 tput = args.batch * args.seq * (t - start + 1) / max(dt, 1e-9)
                 print(f"[train] step {t:5d} loss {loss:7.4f} "
@@ -99,7 +128,7 @@ def main(argv=None):
     if args.ckpt_dir:
         save_state(state, args.ckpt_dir, step=args.steps)
     print("[train] done")
-    return state
+    return TrainRun(state, losses, compiled, batch)
 
 
 if __name__ == "__main__":

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compression
+from repro.dist import sharding
 from repro.models import transformer, transformer_scan
 from repro.models.common import ModelConfig
 from repro.optim.optimizers import (Optimizer, apply_updates,
@@ -134,11 +135,12 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
             if step_cfg.error_feedback:
                 # v survives the qdq (residual needs it) -> no donation
                 v = gflat + state["ec_err"]
-                qflat = q_codec.flat_qdq(v, qkey)
+                qflat = sharding.on_every_device(q_codec.flat_qdq)(v, qkey)
                 new_state["ec_err"] = v - qflat
             else:
                 # gflat is dead after the qdq -> donate its storage
-                qflat = q_codec.flat_qdq(gflat, qkey, donate=True)
+                qflat = sharding.on_every_device(
+                    partial(q_codec.flat_qdq, donate=True))(gflat, qkey)
             grads = layout.unflatten(qflat)
             # measured wire bytes of the one fused gradient message (a
             # trace-time constant: shapes are static under jit)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.launch import hlo_analysis as H
+from repro.launch import mesh as mesh_lib
 
 SYNTH = """
 HloModule test
@@ -63,7 +64,7 @@ def test_scan_vs_unroll_parity_on_device():
     from repro.launch.dryrun import _state_shardings
 
     cfg = configs.get_config("qwen1.5-0.5b").reduced(n_layers=3)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
     opt = make_optimizer("adamw", 1e-3)
     batch = make_batch_shapes(cfg, InputShape("t", 64, 4, "train"),
                               dtype=jnp.float32)
@@ -72,7 +73,7 @@ def test_scan_vs_unroll_parity_on_device():
         scfg = steps.TrainStepConfig(remat=False, scan_layers=scan)
         state = steps.abstract_train_state(cfg, opt, step_cfg=scfg)
         fn = steps.make_train_step(cfg, opt, scfg)
-        with mesh:
+        with jax.set_mesh(mesh):
             j = jax.jit(fn, in_shardings=(
                 _state_shardings(state, mesh),
                 sharding.batch_shardings(batch, mesh)))
