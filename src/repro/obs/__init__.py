@@ -1,7 +1,8 @@
 """Unified telemetry tier: structured tracing, metrics, flight recorder.
 
-Zero-dependency (stdlib-only core; jax touched lazily and only for
-``named_scope`` annotations), off by default, threaded through every
+Zero-dependency (stdlib-only core; jax touched lazily: kernel names as
+``jax.named_scope``, always open, and host spans as profiler
+annotations where jax is loaded), off by default, threaded through every
 layer of the stack:
 
   state.py    master switches (``REPRO_OBS=1`` env or ``obs.enable()``)
@@ -13,15 +14,17 @@ layer of the stack:
               quorum counts, per-bucket quant range)
   flight.py   bounded ring buffer of recent events, dumped to disk on
               fault-ledger validation failure or uncaught scheduler
-              exception; jax.named_scope hooks for the Pallas kernels
+              exception; jax.named_scope names for the Pallas kernels
   runinfo.py  run_id (git SHA + seed) + schema version stamped on every
               BENCH row, timeline, and flight dump
   export.py   ``python -m repro.obs.export trace`` — openable timeline
 
-Instrumentation contract: every call site guards on ``obs.enabled(...)``
-(one dict lookup when off); values inside ``jit`` are never recorded at
-trace time — they ride out as auxiliary outputs and are observed on the
-host (``metrics.observe_array`` skips tracers).
+Instrumentation contract: every recording call site guards on
+``obs.enabled(...)`` (one dict lookup when off); the kernel scopes and
+profiler annotations, which record nothing themselves, are not guarded.
+Values inside ``jit`` are never recorded at trace time — they ride out
+as auxiliary outputs and are observed on the host
+(``metrics.observe_array`` skips tracers).
 """
 from repro.obs.flight import (kernel_scope, record as flight_record,
                               recorder as flight_recorder)

@@ -17,15 +17,15 @@ Dumps land in ``REPRO_OBS_DIR`` (default: the current directory) as
 cross-referenceable with BENCH rows and exported timelines through the
 shared ``run_id``.
 
-``kernel_scope(name)`` is the jax-profiler annotation hook for the
-bucketed Pallas kernels: ``jax.named_scope`` when tracing is enabled
-(names show up in ``jax.profiler`` traces and HLO metadata), a no-op
-nullcontext otherwise. jax is imported lazily so the obs package stays
+``kernel_scope(name)`` is the naming hook for the bucketed Pallas
+kernels: a ``jax.named_scope``, always open, so the kernel's name reaches
+the lowered HLO's metadata and every profiler trace of the compiled
+program. A scope is compile-time metadata and costs nothing at run time,
+so no switch guards it. jax is imported lazily so the obs package stays
 importable without it.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import itertools
 import json
@@ -137,11 +137,9 @@ def guarded(scope: str):
 
 
 def kernel_scope(name: str):
-    """``jax.named_scope`` around a kernel call when tracing is on —
-    the annotation shows up in jax.profiler timelines and in the lowered
-    HLO's metadata — else a free nullcontext."""
-    if not state.enabled("trace"):
-        return contextlib.nullcontext()
+    """``jax.named_scope`` around a kernel call: the name lands in the
+    lowered HLO's ``op_name`` metadata and so in jax.profiler timelines,
+    whether or not obs tracing is on."""
     import jax
 
     return jax.named_scope(name)

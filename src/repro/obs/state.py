@@ -7,8 +7,7 @@ one dict lookup on a module-level dict — the measured overhead budget
 module must stay dependency-free and branch-cheap.
 
 Kinds:
-  trace    span/event tracer (repro.obs.trace) + jax.named_scope kernel
-           annotations
+  trace    span/event tracer (repro.obs.trace): Chrome-trace recording
   metrics  counters/gauges/histograms (repro.obs.metrics)
   flight   bounded ring buffer of recent events (repro.obs.flight)
 

@@ -31,11 +31,15 @@ rows to the same tracks when tracing is enabled during scheduling.
 
 Sim seconds are exported as microseconds (ts = t * 1e6); wall spans use
 microseconds since the tracer's first event. Zero dependencies beyond
-the stdlib.
+the stdlib: where jax is already loaded, a wall span is also a
+``jax.profiler.TraceAnnotation``, so that a profiler trace holds it on
+the device trace's clock.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -123,22 +127,25 @@ class Tracer:
     def span(self, name: str, *, cat: str = "host",
              args: Optional[dict] = None):
         """Wall-clock span on the host track (monotonic clock); records
-        only if tracing is enabled at entry."""
-        if not state.enabled("trace"):
-            yield
-            return
-        if self._t0_ns is None:
-            self._t0_ns = time.perf_counter_ns()
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter_ns()
-            self._append({"name": name, "cat": cat, "ph": "X",
-                          "ts": (t0 - self._t0_ns) / 1e3,
-                          "dur": (t1 - t0) / 1e3, "pid": _pid(HOST),
-                          "tid": self._tid(HOST, "host"),
-                          "args": args or {}})
+        only if tracing is enabled at entry. Where jax is loaded, the span
+        is also a ``jax.profiler.TraceAnnotation``, whether or not tracing
+        is on, so a profiler trace shows it on the device trace's clock."""
+        with _profiler_annotation(name):
+            if not state.enabled("trace"):
+                yield
+                return
+            if self._t0_ns is None:
+                self._t0_ns = time.perf_counter_ns()
+            t0 = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                t1 = time.perf_counter_ns()
+                self._append({"name": name, "cat": cat, "ph": "X",
+                              "ts": (t0 - self._t0_ns) / 1e3,
+                              "dur": (t1 - t0) / 1e3, "pid": _pid(HOST),
+                              "tid": self._tid(HOST, "host"),
+                              "args": args or {}})
 
     # -- export -----------------------------------------------------------
 
@@ -180,6 +187,15 @@ class Tracer:
     def events(self) -> list[dict]:
         with self._lock:
             return list(self._events)
+
+
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` where jax is already
+    imported (a span never imports it), else a free nullcontext."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 _TRACER = Tracer()

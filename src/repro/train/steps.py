@@ -102,9 +102,10 @@ def make_loss_fn(cfg: ModelConfig,
         kw = {}
         if step_cfg.scan_layers:
             kw["remat_policy"] = step_cfg.remat_policy
-        return impl.loss_fn(params, cfg, batch,
-                            use_flash=step_cfg.use_flash,
-                            remat=step_cfg.remat, **kw)
+        with jax.named_scope("train.forward"):
+            return impl.loss_fn(params, cfg, batch,
+                                use_flash=step_cfg.use_flash,
+                                remat=step_cfg.remat, **kw)
 
     return loss
 
@@ -118,42 +119,53 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
     def train_step(state: dict, batch: dict) -> tuple[dict, dict]:
         loss_val, grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch))(state["params"])
-        if step_cfg.grad_clip > 0:
-            grads, grad_norm = clip_by_global_norm(grads, step_cfg.grad_clip)
-        else:
-            grad_norm = jnp.zeros(())
+        with jax.named_scope("train.clip"):
+            if step_cfg.grad_clip > 0:
+                grads, grad_norm = clip_by_global_norm(grads,
+                                                       step_cfg.grad_clip)
+            else:
+                grad_norm = jnp.zeros(())
 
         new_state = dict(state)
         comm_bytes = 0.0
         if step_cfg.grad_compression != "none":
-            qkey = jax.random.fold_in(state["rng"], state["step"])
+            with jax.named_scope("train.codec"):
+                qkey = jax.random.fold_in(state["rng"], state["step"])
             # fused flat-buffer path: flatten once (single-buffer writes,
             # layout from the lru cache), quantize per bucket in one
             # pass, ship ONE message
             layout = compression.FlatLayout.from_tree(grads)
-            gflat = layout.flatten(grads)
+            with jax.named_scope("train.flatten"):
+                gflat = layout.flatten(grads)
             if step_cfg.error_feedback:
                 # v survives the qdq (residual needs it) -> no donation
-                v = gflat + state["ec_err"]
-                qflat = sharding.on_every_device(q_codec.flat_qdq)(v, qkey)
-                new_state["ec_err"] = v - qflat
+                with jax.named_scope("train.error_feedback"):
+                    v = gflat + state["ec_err"]
+                with jax.named_scope("train.codec"):
+                    qflat = sharding.on_every_device(q_codec.flat_qdq)(
+                        v, qkey)
+                with jax.named_scope("train.error_feedback"):
+                    new_state["ec_err"] = v - qflat
             else:
                 # gflat is dead after the qdq -> donate its storage
-                qflat = sharding.on_every_device(
-                    partial(q_codec.flat_qdq, donate=True))(gflat, qkey)
-            grads = layout.unflatten(qflat)
+                with jax.named_scope("train.codec"):
+                    qflat = sharding.on_every_device(
+                        partial(q_codec.flat_qdq, donate=True))(gflat, qkey)
+            with jax.named_scope("train.unflatten"):
+                grads = layout.unflatten(qflat)
             # measured wire bytes of the one fused gradient message (a
             # trace-time constant: shapes are static under jit)
             comm_bytes = q_codec.tree_wire_bytes_flat(grads)
 
-        updates, new_opt = optimizer.update(grads, state["opt"],
-                                            state["params"])
-        new_state["params"] = apply_updates(state["params"], updates)
-        new_state["opt"] = new_opt
-        new_state["step"] = state["step"] + 1
-        metrics = {"loss": loss_val, "grad_norm": grad_norm,
-                   "step": state["step"],
-                   "comm_bytes": jnp.asarray(comm_bytes, jnp.float32)}
+        with jax.named_scope("train.optimizer"):
+            updates, new_opt = optimizer.update(grads, state["opt"],
+                                                state["params"])
+            new_state["params"] = apply_updates(state["params"], updates)
+            new_state["opt"] = new_opt
+            new_state["step"] = state["step"] + 1
+            metrics = {"loss": loss_val, "grad_norm": grad_norm,
+                       "step": state["step"],
+                       "comm_bytes": jnp.asarray(comm_bytes, jnp.float32)}
         return new_state, metrics
 
     return train_step

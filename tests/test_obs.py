@@ -21,9 +21,11 @@ Covers the observability contracts that CI leans on:
     ``bench_delta`` tolerates (but announces) rows gaining columns.
 """
 import dataclasses
+import glob
 import importlib.util
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -312,10 +314,55 @@ def test_kernel_annotation_is_transparent():
     def f(x, y=1):
         return x + y
 
-    assert f(2) == 3                    # tier off: plain passthrough
+    assert f(2) == 3                    # tier off: named_scope wraps it
     state.enable(trace=True, metrics=False, flight=False)
-    assert f(2, y=3) == 5               # tier on: named_scope wraps it
+    assert f(2, y=3) == 5               # tier on: the same scope
     assert f.__name__ == "f"            # wraps() keeps jit-able identity
+
+
+def test_kernel_names_reach_the_lowered_hlo_with_obs_off():
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.quant import ops
+
+    assert not state.enabled("trace")
+    x = jnp.linspace(-1.0, 1.0, 4096)
+    lowered = jax.jit(lambda v, k: ops.qdq_flat(v, k)).lower(
+        x, jax.random.PRNGKey(0))
+    hlo = lowered.as_text(dialect="hlo", debug_info=True)
+    assert "quant.qdq_flat" in hlo
+    assert "_qdq_flat_impl" in hlo      # the jitted name stays as it was
+
+
+def test_span_lands_on_the_profiler_trace_around_its_device_work(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x.T).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()             # compiled outside the trace
+    hlo_names = set(re.findall(r"%([\w.\-]+) = ",
+                               f.lower(x).compile().as_text()))
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("unit.span"):   # obs tracing is off
+            f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    planes = jax.profiler.ProfileData.from_file(path).planes
+    host = [p for p in planes if p.name == "/host:CPU"]
+    events = [ev for p in host for line in p.lines for ev in line.events]
+    spans = [ev for ev in events if ev.name == "unit.span"]
+    assert len(spans) == 1
+    lo = spans[0].start_ns
+    hi = lo + spans[0].duration_ns
+    work = [ev for ev in events if ev.name in hlo_names]
+    assert work, "the jitted call's operations are not in the trace"
+    for ev in work:
+        assert lo <= ev.start_ns and ev.start_ns + ev.duration_ns <= hi
+    assert obs_trace.tracer().n_events == 0   # the JSON stays off
 
 
 # ---------------------------------------------------------------------------
