@@ -11,7 +11,8 @@ Layout/tiling rationale (TPU v5e):
     reads these as scalars anyway); per-bucket stats are written to a
     whole-array SMEM output the same way;
   * pure VPU elementwise work, no MXU; stochastic rounding compares the
-    uniform draw against the fractional part.
+    uniform draw against the fractional part (the flat qdq kernel draws
+    it in VMEM, the others read it from HBM).
 
 Wire format (sub-byte packing): for b-bit codes, pack = 8 // b codes share
 one uint8. The wrapper views the padded flat input as (pack, R, C) — pack
@@ -33,6 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -149,23 +151,13 @@ def decode_packed(payload: jnp.ndarray, params: jnp.ndarray, *, bits: int,
 # ---------------------------------------------------------------------------
 
 
-def _qdq_bucketed_kernel(params_ref, x_ref, u_ref, o_ref, *, levels: int):
-    """x_ref, u_ref, o_ref: (1, pack, BLOCK_R, C); params_ref is the FULL
-    (n_buckets, 2) params array, resident in SMEM for the whole grid (no
-    per-step refetch of the (lo, scale) row; the kernel picks its bucket's
-    row by program id)."""
-    bi = pl.program_id(0)
-    lo = params_ref[bi, 0]
-    scale = params_ref[bi, 1]
-    q = _quantize(x_ref[...], u_ref[...], lo, scale, levels)
-    o_ref[...] = (q * scale + lo).astype(o_ref.dtype)
-
-
 def _encode_packed_bucketed_kernel(params_ref, x_ref, u_ref, o_ref, *,
                                    bits: int):
     """x_ref, u_ref: (1, pack, BLOCK_R, C) — one bucket's row tile, all
     segments; o_ref: (1, BLOCK_R, C) packed payload tile; params_ref: the
-    full SMEM-resident (n_buckets, 2) array (see _qdq_bucketed_kernel)."""
+    FULL (n_buckets, 2) params array, resident in SMEM for the whole grid
+    (no per-step refetch of the (lo, scale) row; the kernel picks its
+    bucket's row by program id)."""
     pack = 8 // bits
     levels = (1 << bits) - 1
     bi = pl.program_id(0)
@@ -189,22 +181,6 @@ def _decode_packed_bucketed_kernel(params_ref, c_ref, o_ref, *, bits: int):
     o_ref[0, 0] = (field.astype(jnp.float32) * scale + lo).astype(o_ref.dtype)
 
 
-def qdq_bucketed(x4: jnp.ndarray, u4: jnp.ndarray, params: jnp.ndarray, *,
-                 bits: int, block_r: int, interpret: bool) -> jnp.ndarray:
-    """x4, u4: (B, pack, Rb, C); params: (B, 2). Returns dequantized x4."""
-    b, pack, r, c = x4.shape
-    kernel = functools.partial(_qdq_bucketed_kernel, levels=(1 << bits) - 1)
-    seg = pl.BlockSpec((1, pack, block_r, c), lambda bi, i: (bi, 0, i, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(b, pl.cdiv(r, block_r)),
-        in_specs=[_SMEM, seg, seg],
-        out_specs=seg,
-        out_shape=jax.ShapeDtypeStruct((b, pack, r, c), x4.dtype),
-        interpret=interpret,
-    )(params, x4, u4)
-
-
 def encode_packed_bucketed(x4: jnp.ndarray, u4: jnp.ndarray,
                            params: jnp.ndarray, *, bits: int, block_r: int,
                            interpret: bool) -> jnp.ndarray:
@@ -223,51 +199,148 @@ def encode_packed_bucketed(x4: jnp.ndarray, u4: jnp.ndarray,
     )(params, x4, u4)
 
 
-def _minmax_bucketed_kernel(x_ref, o_ref, *, n_rows: int, block_r: int):
-    """x_ref: (1, BLOCK_R, C) one bucket's row tile; o_ref: the whole
-    (B, 2) SMEM output, whose row bi holds the bucket's [lo, hi],
-    accumulated across the (sequential) row-tile grid dimension — a
-    single-read fused min+max reduction. Rows past
-    n_rows (grid padding of the last tile) are masked out of the
-    reduction: padded values must never touch the bucket's range."""
-    bi = pl.program_id(0)
-    i = pl.program_id(1)
-    x = x_ref[0]
-    row = i * block_r + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    valid = row < n_rows
-    tile_lo = jnp.min(jnp.where(valid, x, jnp.inf))
-    tile_hi = jnp.max(jnp.where(valid, x, -jnp.inf))
+# ---------------------------------------------------------------------------
+# Flat-buffer quantize-dequantize in two passes over the caller's unpadded
+# (N,) buffer, viewed as (R, ROW) rows — a bitcast of the flat array when
+# N % ROW == 0. A bucket is a whole number of (8, ROW) tiles (ops.flat_
+# geometry aligns its cap to 8 * ROW elements) and each grid block lies in
+# one bucket (ops picks BLOCK_R to divide the bucket's rows), so a block
+# reads its bucket's row of the SMEM-resident (n_buckets, 2) tables as
+# scalars. Rows past the end of the view (the ragged last block) and
+# elements past N are masked out of the stats; the coding pass writes them
+# nowhere that survives (Pallas drops a partial block's out-of-bounds rows).
+#
+# The coding pass draws its own uniforms: under jax_threefry_partitionable,
+# jax.random.uniform(k, shape) gives the element at C-order index i from
+# threefry2x32(k, (0, i)), whatever the shape, so bucket b's element c
+# (its C-order index in the bucket's (pack, rows, 512) draw) gets
+# threefry_uniform(fold_in(key, b), c) — the same bits the jnp backend and
+# encode_flat draw in HBM, computed in VMEM instead.
+# ---------------------------------------------------------------------------
 
-    @pl.when(i == 0)
+ROW = 128
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+_THREEFRY_PARITY = 0x1BD11BDA
+
+
+def threefry_uniform(k0, k1, ctr):
+    """``jax.random.uniform``'s float32 draw for counter ``ctr`` under the
+    raw threefry key (k0, k1), bit for bit.
+
+    All words are int32 bit patterns: adds wrap and right shifts are
+    logical, so every op matches its uint32 counterpart. The counter's
+    high word is 0 (a bucket holds under 2**32 elements). The two output
+    words are XORed into 32 random bits; the top 23 become the mantissa
+    of a float in [1, 2), minus 1."""
+    ks = (k0, k1, k0 ^ k1 ^ _THREEFRY_PARITY)
+    x0 = k0
+    x1 = ctr + k1
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i % 2]:
+            x0 = x0 + x1
+            x1 = (x1 << r) | lax.shift_right_logical(x1, 32 - r)
+            x1 = x0 ^ x1
+        x0 = x0 + ks[(i + 1) % 3]
+        x1 = x1 + (ks[(i + 2) % 3] + (i + 1))
+    bits = lax.shift_right_logical(x0 ^ x1, 9) | 0x3F800000
+    return lax.bitcast_convert_type(bits, jnp.float32) - 1.0
+
+
+def _block_bucket(block_r: int, rows_b: int, nb: int):
+    """The bucket of this grid step's block, and the block's first row."""
+    r0 = pl.program_id(0) * block_r
+    return jnp.minimum(r0 // rows_b, nb - 1), r0
+
+
+def _minmax_flat_kernel(x_ref, o_ref, *, total: int, rows_b: int, nb: int,
+                        block_r: int):
+    """x_ref: (BLOCK_R, ROW) block; o_ref: the whole (nb, 2) SMEM output,
+    row b the bucket's [lo, hi], accumulated over the (sequential) grid."""
+    b, r0 = _block_bucket(block_r, rows_b, nb)
+
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        o_ref[bi, 0] = tile_lo
-        o_ref[bi, 1] = tile_hi
+        def row(j, carry):
+            o_ref[j, 0] = jnp.float32(jnp.inf)
+            o_ref[j, 1] = jnp.float32(-jnp.inf)
+            return carry
+        lax.fori_loop(0, nb, row, 0)
 
-    @pl.when(i > 0)
-    def _acc():
-        o_ref[bi, 0] = jnp.minimum(o_ref[bi, 0], tile_lo)
-        o_ref[bi, 1] = jnp.maximum(o_ref[bi, 1], tile_hi)
+    x = x_ref[...]
+    idx = (lax.broadcasted_iota(jnp.int32, x.shape, 0) * ROW
+           + lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    valid = idx < total - r0 * ROW
+    o_ref[b, 0] = jnp.minimum(o_ref[b, 0],
+                              jnp.min(jnp.where(valid, x, jnp.inf)))
+    o_ref[b, 1] = jnp.maximum(o_ref[b, 1],
+                              jnp.max(jnp.where(valid, x, -jnp.inf)))
 
 
-def minmax_bucketed(x3: jnp.ndarray, *, block_r: int,
-                    interpret: bool) -> jnp.ndarray:
-    """x3: (B, R, C) fp32 bucket view -> (B, 2) per-bucket [lo, hi].
-
-    One read of the buffer (min and max in the same pass), vs the two
-    separate reduction passes of jnp.min + jnp.max. min/max accumulate
-    exactly, so the result is bit-identical to the jnp reference.
-    """
-    b, r, c = x3.shape
-    kernel = functools.partial(_minmax_bucketed_kernel, n_rows=r,
-                               block_r=block_r)
+def minmax_flat(x2: jnp.ndarray, *, total: int, rows_b: int, nb: int,
+                block_r: int, interpret: bool) -> jnp.ndarray:
+    """x2: (R, ROW) view of a flat buffer of ``total`` elements ->
+    (nb, 2) per-bucket [lo, hi] in one read. min/max are exact, so the
+    result equals any other reduction order's bit for bit."""
+    r, c = x2.shape
+    assert c == ROW and (nb == 1 or rows_b % block_r == 0), (
+        x2.shape, rows_b, block_r)
+    kernel = functools.partial(_minmax_flat_kernel, total=total,
+                               rows_b=rows_b, nb=nb, block_r=block_r)
     return pl.pallas_call(
         kernel,
-        grid=(b, pl.cdiv(r, block_r)),
-        in_specs=[pl.BlockSpec((1, block_r, c), lambda bi, i: (bi, i, 0))],
+        grid=(pl.cdiv(r, block_r),),
+        in_specs=[pl.BlockSpec((block_r, c), lambda i: (i, 0))],
         out_specs=_SMEM,
-        out_shape=jax.ShapeDtypeStruct((b, 2), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb, 2), jnp.float32),
         interpret=interpret,
-    )(x3)
+    )(x2)
+
+
+def _qdq_flat_kernel(keys_ref, params_ref, x_ref, o_ref, *, levels: int,
+                     rows_b: int, nb: int, block_r: int, slab: int):
+    """keys_ref: (nb, 2) int32 bucket keys and params_ref: (nb, 2) [lo,
+    scale], both whole in SMEM; x_ref, o_ref: (BLOCK_R, ROW) blocks,
+    coded ``slab`` rows at a time so the threefry rounds stay in vregs."""
+    b, r0 = _block_bucket(block_r, rows_b, nb)
+    k0, k1 = keys_ref[b, 0], keys_ref[b, 1]
+    lo, scale = params_ref[b, 0], params_ref[b, 1]
+    # counter of the block's first element in its bucket's draw
+    first = (r0 - b * rows_b) * ROW
+    offset = (lax.broadcasted_iota(jnp.int32, (slab, ROW), 0) * ROW
+              + lax.broadcasted_iota(jnp.int32, (slab, ROW), 1))
+
+    def code(s, carry):
+        rows = pl.ds(pl.multiple_of(s * slab, slab), slab)
+        u = threefry_uniform(k0, k1, offset + (first + s * slab * ROW))
+        q = _quantize(x_ref[rows, :], u, lo, scale, levels)
+        o_ref[rows, :] = (q * scale + lo).astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, block_r // slab, code, 0)
+
+
+def qdq_flat(x2: jnp.ndarray, keys: jnp.ndarray, params: jnp.ndarray, *,
+             bits: int, rows_b: int, block_r: int, slab: int,
+             interpret: bool) -> jnp.ndarray:
+    """x2: (R, ROW) view of a flat buffer; keys: (nb, 2) int32 raw
+    threefry keys of the buckets; params: (nb, 2) [lo, scale]. Returns
+    the dequantized (R, ROW) view."""
+    r, c = x2.shape
+    nb = params.shape[0]
+    assert c == ROW and block_r % slab == 0 and (
+        nb == 1 or rows_b % block_r == 0), (x2.shape, rows_b, block_r, slab)
+    kernel = functools.partial(_qdq_flat_kernel, levels=(1 << bits) - 1,
+                               rows_b=rows_b, nb=nb, block_r=block_r,
+                               slab=slab)
+    block = pl.BlockSpec((block_r, c), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(r, block_r),),
+        in_specs=[_SMEM, _SMEM, block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((r, c), x2.dtype),
+        interpret=interpret,
+    )(keys, params, x2)
 
 
 # ---------------------------------------------------------------------------

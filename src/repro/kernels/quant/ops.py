@@ -1,17 +1,22 @@
 """jit'd wrappers for the quantization kernels, with backend dispatch.
 
-Handles arbitrary shapes (pad + reshape to C=512 lanes), draws the
-uniforms, computes global (lo, scale), picks BLOCK_R per kernel from the
-actual resident operand dtypes, and dispatches between the two backends:
+Handles arbitrary shapes, computes (lo, scale), picks BLOCK_R per kernel
+from the actual resident operand dtypes, and dispatches between the two
+backends:
 
   backend='pallas'  the TPU kernels (interpret=True off-TPU)
   backend='jnp'     the pure-jnp reference (ref.py)
   backend='auto'    pallas on TPU, jnp elsewhere
 
-Both backends consume the *same* (lo, scale) and the same uniform draws —
+The per-leaf and packed-wire paths pad + reshape to C=512 lanes and draw
+their uniforms in HBM with `jax.random.uniform`; the flat-buffer qdq on
+the Pallas backend reads the caller's unpadded buffer and draws the same
+uniforms inside its kernel (kernel.threefry_uniform). Both backends
+consume the *same* (lo, scale) and the same per-element uniforms —
 `jax.random.uniform` fills shapes in flat C-order, so the (pack, R, C)
 segment view of encode and the (R*pack, C) view of qdq read identical
-per-element uniforms. Consequence (asserted in tests/test_codec.py):
+per-element uniforms. Consequence (asserted in tests/test_codec.py and
+tests/test_flat_codec.py):
 
     decode(encode(x, key)) == quantize_dequantize(x, key)   bit-for-bit
     pallas(interpret) == jnp                                bit-for-bit
@@ -24,6 +29,7 @@ of pack * 512 elements; payload bytes = ceil(n / (pack*512)) * 512.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -66,15 +72,15 @@ def _block_r(c: int, io_bytes: int, f32_elems: int) -> int:
     the body's temporaries: about two fp32 values per fp32 element of the
     tile (``f32_elems`` of them per row element) and one int32 packing
     accumulator. ``io_bytes`` sums the tiles' bytes per row element: qdq
-    has x, u and out fp32 (12, times pack for the bucketed segment view);
-    a packed encode has pack fp32 x-segments, pack fp32 u-segments and one
-    uint8 out (8 * pack + 1); decode has one uint8 in and one fp32 out (5).
-    Checked against the v5e compiler at rq8/rq4/rq2 by
-    tests/test_tpu_compile.py.
+    has x, u and out fp32 (12); a packed encode has pack fp32 x-segments,
+    pack fp32 u-segments and one uint8 out (8 * pack + 1); decode has one
+    uint8 in and one fp32 out (5); the flat qdq has x and out fp32 (8)
+    and keeps its temporaries per slab, not per tile (0). Checked against
+    the v5e compiler at rq8/rq4/rq2 by tests/test_tpu_compile.py.
     """
     per_elem = 2 * io_bytes + 8 * f32_elems + 4
     rows = VMEM_BUDGET // (per_elem * c)
-    rows = max(8, min(1024, rows))
+    rows = max(8, min(1024 * LANES // c, rows))   # <= 512Ki elements
     return int(rows) & ~7 or 8   # multiple of 8 sublanes
 
 
@@ -170,8 +176,10 @@ def decode(payload: jnp.ndarray, params: jnp.ndarray, *, shape: tuple,
 # segment packing interleaves the whole bucket range into every row. So the
 # whole-tree message pays at most ONE pad granule (the tail's) plus one
 # 8-byte params row per bucket — vs one granule + one row per leaf on the
-# per-leaf paths. Kernel cost is O(1) in the leaf count: one bucketed call
-# for the full buckets + one per-leaf-style call for the tail.
+# per-leaf paths. Kernel cost is O(1) in the leaf count: the wire kernels
+# make one bucketed call for the full buckets + one per-leaf-style call for
+# the tail; the flat qdq makes one stats call and one coding call over the
+# whole unpadded buffer.
 # ---------------------------------------------------------------------------
 
 
@@ -204,25 +212,53 @@ def _stack2(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
                                     (0, 1))
 
 
-def bucket_params(x2: jnp.ndarray, *, bits: int,
+def _rows(flat: jnp.ndarray) -> jnp.ndarray:
+    """The flat buffer as (R, kernel.ROW) rows: a bitcast when its length
+    is a multiple of ROW, else after a zero pad to the next row (the flat
+    kernels mask or drop the pad)."""
+    pad = -flat.shape[0] % kernel.ROW
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    return flat.reshape(-1, kernel.ROW)
+
+
+def _flat_block_r(block_r: int, rows_b: int, nb: int) -> int:
+    """``block_r`` cut to a multiple of 8 that divides the bucket's rows
+    when there are several buckets, so every block of the flat kernels
+    lies in one bucket (``flat_geometry`` makes rows_b a multiple of 8)."""
+    if nb > 1:
+        block_r = min(block_r, rows_b)
+        while rows_b % block_r:
+            block_r -= 8
+    return block_r
+
+
+def bucket_params(flat: jnp.ndarray, *, bits: int, bucket_elems: int,
                   backend: str) -> jnp.ndarray:
-    """Per-bucket (n_buckets, 2) [lo, scale] rows in ONE read of the
-    buffer: min and max come out of the same reduction pass (the Pallas
-    ``minmax_bucketed`` kernel on the pallas backend, a variadic
-    ``lax.reduce`` on the jnp reference) instead of the separate min pass
-    + max pass. The stats pass cannot fuse further into the encode kernel
-    itself — stochastic rounding needs the bucket-global (lo, scale)
-    before any element can be coded — so the flat pipeline's floor is two
-    reads: one fused stats pass + one encode pass."""
+    """Per-bucket (n_buckets, 2) [lo, scale] rows of a flat buffer in ONE
+    read: min and max come out of the same reduction pass (the Pallas
+    ``minmax_flat`` kernel over the unpadded buffer, masked past its end,
+    on the pallas backend; a variadic ``lax.reduce`` over the edge-padded
+    (n_buckets, cap) view on the jnp reference — the edge pad repeats the
+    last real element, so both see the same (lo, hi)). The stats pass
+    cannot fuse further into the coding kernel itself — stochastic
+    rounding needs the bucket-global (lo, scale) before any element can
+    be coded — so the flat pipeline's floor is two reads: one fused stats
+    pass + one coding pass."""
+    total = flat.shape[0]
+    _, cap, nb, _, _ = flat_geometry(total, bits=bits,
+                                     bucket_elems=bucket_elems)
     levels = (1 << bits) - 1
     if _use_pallas(backend):
-        nb, cap = x2.shape
-        mm = kernel.minmax_bucketed(
-            x2.reshape(nb, cap // LANES, LANES),
-            block_r=_block_r(LANES, 4, 1), interpret=_interpret())
+        rows_b = cap // kernel.ROW
+        mm = kernel.minmax_flat(
+            _rows(flat), total=total, rows_b=rows_b, nb=nb,
+            block_r=_flat_block_r(_block_r(kernel.ROW, 4, 1), rows_b, nb),
+            interpret=_interpret())
         lo, hi = mm[:, 0], mm[:, 1]
     else:
-        lo, hi = ref.minmax_bucketed(x2)
+        lo, hi = ref.minmax_bucketed(edge_pad(flat, nb * cap).reshape(nb,
+                                                                      cap))
     scale = jnp.where(hi > lo, (hi - lo) / levels, 1.0)
     return _stack2(lo, scale)
 
@@ -256,8 +292,10 @@ def flat_geometry(total: int, *, bits: int,
     """Static bucket geometry for a flat buffer of `total` elements.
 
     Returns (pack, cap, n_buckets, rows_per_bucket, rows_kept):
-      cap             elements per full bucket (granule-aligned cap on
-                      `bucket_elems`, shrunk for small buffers);
+      cap             elements per full bucket (`bucket_elems`, shrunk
+                      for small buffers, rounded up to a whole granule
+                      and a whole number of the flat kernels' (8, 128)
+                      tiles);
       rows_per_bucket payload rows each full bucket contributes;
       rows_kept       total payload rows on the wire — Rb per full bucket
                       plus the tail bucket's granule-aligned Rt.
@@ -266,7 +304,7 @@ def flat_geometry(total: int, *, bits: int,
         raise ValueError(f"empty flat buffer (total={total})")
     pack = 8 // bits
     granule = pack * LANES                      # elements per payload row
-    cap = _align_up(min(bucket_elems, total), granule)
+    cap = _align_up(min(bucket_elems, total), max(granule, 8 * kernel.ROW))
     n_buckets = -(-total // cap)
     rows_b = cap // granule
     tail = total - (n_buckets - 1) * cap        # in (0, cap]
@@ -276,11 +314,30 @@ def flat_geometry(total: int, *, bits: int,
 
 def bucket_key(key, b):
     """Bucket b's uniform-draw key: fold_in(key, b). The SINGLE source of
-    per-bucket randomness for every fused path — the vectorized
-    encode_flat/qdq_flat (vmapped draw, bit-identical to per-key draws
-    because threefry is counter-based) AND the cache-blocked from-tree
-    encode draw the exact same bits per bucket."""
+    per-bucket randomness for every fused path — encode_flat and the jnp
+    qdq_flat draw under it in HBM (one vmapped draw, bit-identical to
+    per-key draws because threefry is counter-based), the cache-blocked
+    from-tree encode draws under it per bucket, and the Pallas qdq_flat
+    hands its raw words to the coding kernel, which draws the same bits
+    in VMEM (``bucket_keys``, ``kernel.threefry_uniform``)."""
     return jax.random.fold_in(key, b)
+
+
+def bucket_keys(key, nb: int) -> jnp.ndarray:
+    """(nb, 2) int32 raw threefry words of ``bucket_key(key, b)`` for every
+    bucket: what the Pallas coding kernel draws under. Its formula is
+    jax.random.uniform's under ``jax_threefry_partitionable``, so anything
+    else is refused rather than drawn differently."""
+    keys = jax.vmap(partial(bucket_key, key))(jnp.arange(nb))
+    if jnp.issubdtype(keys.dtype, jax.dtypes.prng_key):
+        keys = jax.random.key_data(keys)
+    if not jax.config.jax_threefry_partitionable or keys.shape != (nb, 2):
+        raise ValueError(
+            "the Pallas flat codec draws jax.random.uniform's bits for "
+            "threefry2x32 keys under jax_threefry_partitionable=True; got "
+            f"key words {keys.shape}, partitionable="
+            f"{jax.config.jax_threefry_partitionable}")
+    return lax.bitcast_convert_type(keys, jnp.int32)
 
 
 def _bucket_views(flat: jnp.ndarray, key, *, bits: int, bucket_elems: int,
@@ -288,16 +345,16 @@ def _bucket_views(flat: jnp.ndarray, key, *, bits: int, bucket_elems: int,
     """Split a flat buffer into head/tail segment views + per-bucket params.
 
     The buffer is edge-padded ONCE (single-buffer writes, no concatenate)
-    to n_buckets * cap; every view below — the (nb, cap) stats view, the
-    head's (B-1, pack, Rb, C) segments, the tail's (pack, Rt, C) segments
-    — is a slice/reshape of that one padded buffer, so nothing else is
-    materialized. Per-bucket [lo, scale] come from ``bucket_params``
-    (min+max fused into one reduction read). Edge padding repeats the
-    last REAL element, so the pad never perturbs the tail bucket's
-    (lo, hi). Uniforms are drawn PER BUCKET under ``bucket_key(key, b)``
-    (head buckets via one vmapped draw), so qdq_flat, encode_flat, and
-    the cache-blocked from-tree encode all consume identical per-element
-    randomness (bit-identical results)."""
+    to n_buckets * cap; the head's (B-1, pack, Rb, C) segments and the
+    tail's (pack, Rt, C) segments are slices/reshapes of that one padded
+    buffer. Per-bucket [lo, scale] come from ``bucket_params`` (min+max
+    fused into one reduction read). Edge padding repeats the last REAL
+    element, so the pad never perturbs the tail bucket's (lo, hi).
+    Uniforms are drawn PER BUCKET under ``bucket_key(key, b)`` (head
+    buckets via one vmapped draw), so encode_flat, the jnp qdq_flat, the
+    cache-blocked from-tree encode and the Pallas qdq_flat's in-kernel
+    draw all consume identical per-element randomness (bit-identical
+    results)."""
     pack, cap, nb, rows_b, _ = flat_geometry(flat.size, bits=bits,
                                              bucket_elems=bucket_elems)
     granule = pack * LANES
@@ -307,7 +364,7 @@ def _bucket_views(flat: jnp.ndarray, key, *, bits: int, bucket_elems: int,
     t = total - head_elems
     rt = -(-t // granule)
     padded = edge_pad(flat, nb * cap)
-    params = bucket_params(padded.reshape(nb, cap), bits=bits,
+    params = bucket_params(flat, bits=bits, bucket_elems=bucket_elems,
                            backend=backend)
     x4 = u4 = None
     if nb > 1:
@@ -340,6 +397,13 @@ def _write_head_tail(head, tail, out_shape, dtype):
     return lax.dynamic_update_slice(out, tail.astype(dtype), off)
 
 
+# Rows the coding kernel codes at a time: each op of the threefry rounds
+# then runs SLAB_ROWS // 8 independent vregs, enough to keep the VPU busy.
+# Swept on a TPU v5e at the repro-100m gradient: 8 / 16 / 32 / 64 / 128 rows
+# took 14.6 / 8.9 / 5.6 / 4.3 / 4.3 ms per qdq_flat call.
+SLAB_ROWS = 64
+
+
 @obs_flight.kernel_annotation("quant.qdq_flat")
 def _qdq_flat_impl(flat: jnp.ndarray, key: jax.Array, *, bits: int = 8,
                    bucket_elems: int = DEFAULT_BUCKET_ELEMS,
@@ -347,27 +411,35 @@ def _qdq_flat_impl(flat: jnp.ndarray, key: jax.Array, *, bits: int = 8,
     """Fused per-bucket Q(x) over a flat buffer (whole pytree, one pass).
 
     Bit-identical to decode_flat(encode_flat(flat, key)) — same uniform
-    draws, same per-bucket params, same rounding."""
+    draws, same per-bucket params, same rounding. On the Pallas backend
+    it is two passes over the caller's unpadded buffer and nothing else
+    of its size: ``bucket_params``' stats kernel, then one coding kernel
+    that draws each element's uniform in VMEM under its bucket's key
+    (``bucket_keys``) and writes the dequantized values straight into the
+    (N,) output. The jnp backend draws the uniforms in HBM over the
+    bucket views of ``_bucket_views``."""
+    if _use_pallas(backend):
+        total = flat.size
+        x = flat.reshape(-1).astype(jnp.float32)
+        _, cap, nb, _, _ = flat_geometry(total, bits=bits,
+                                         bucket_elems=bucket_elems)
+        rows_b = cap // kernel.ROW
+        block_r = _flat_block_r(_block_r(kernel.ROW, 8, 0), rows_b, nb)
+        out = kernel.qdq_flat(
+            _rows(x), bucket_keys(key, nb),
+            bucket_params(x, bits=bits, bucket_elems=bucket_elems,
+                          backend=backend),
+            bits=bits, rows_b=rows_b, block_r=block_r,
+            slab=math.gcd(SLAB_ROWS, block_r), interpret=_interpret())
+        return out.reshape(-1)[:total].astype(flat.dtype)
     x4, u4, x3, u3, params, (pack, nb, _, rt, t) = _bucket_views(
         flat, key, bits=bits, bucket_elems=bucket_elems, backend=backend)
     head = None
-    if _use_pallas(backend):
-        if nb > 1:
-            head = kernel.qdq_bucketed(
-                x4, u4, params[:nb - 1], bits=bits,
-                block_r=_block_r(LANES, 12 * pack, pack),
-                interpret=_interpret()).reshape(-1)
-        tl = kernel.qdq(x3.reshape(pack * rt, LANES),
-                        u3.reshape(pack * rt, LANES), params[nb - 1:nb],
-                        bits=bits, block_r=_block_r(LANES, 12, 1),
-                        interpret=_interpret())
-    else:
-        if nb > 1:
-            head = ref.qdq_bucketed(x4, u4, params[:nb - 1, 0],
-                                    params[:nb - 1, 1],
-                                    bits=bits).reshape(-1)
-        lo, scale = params[nb - 1, 0], params[nb - 1, 1]
-        tl = ref.decode(ref.encode(x3, u3, lo, scale, bits=bits), lo, scale)
+    if nb > 1:
+        head = ref.qdq_bucketed(x4, u4, params[:nb - 1, 0],
+                                params[:nb - 1, 1], bits=bits).reshape(-1)
+    lo, scale = params[nb - 1, 0], params[nb - 1, 1]
+    tl = ref.decode(ref.encode(x3, u3, lo, scale, bits=bits), lo, scale)
     return _write_head_tail(head, tl.reshape(-1)[:t], (flat.size,),
                             flat.dtype)
 

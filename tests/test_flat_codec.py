@@ -137,6 +137,79 @@ def test_flat_qdq_unbiased():
     assert float(jnp.abs(qs.mean(0) - x).max()) < 0.6
 
 
+# (elements, bucket_elems): one bucket; one bucket with repro-100m's
+# remainder (N mod 1024 = 768); three buckets, the same remainder; and a
+# tail bucket of 100 elements, shorter than one 128-lane row
+_FLAT_SHAPES = [(777, 1 << 22), (3 * 1024 + 768, 1 << 22),
+                (5 * 1024 + 768, 2048), (2 * 2048 + 100, 2048)]
+
+
+@pytest.mark.parametrize("bits", [8, 4, 2])
+@pytest.mark.parametrize("n,bucket_elems", _FLAT_SHAPES)
+def test_pallas_qdq_flat_bit_identical(bits, n, bucket_elems):
+    """The Pallas qdq_flat (unpadded buffer, uniforms drawn in the kernel)
+    equals the jnp backend and decode_flat(encode_flat(.)) bit for bit."""
+    x = jax.random.normal(jax.random.PRNGKey(n), (n,)) * 3.0
+    key = jax.random.PRNGKey(bits)
+    kw = dict(bits=bits, bucket_elems=bucket_elems)
+    got = q_ops.qdq_flat(x, key, backend="pallas", **kw)
+    payload, params = q_ops.encode_flat(x, key, backend="jnp", **kw)
+    for want in (q_ops.qdq_flat(x, key, backend="jnp", **kw),
+                 q_ops.decode_flat(payload, params, total=n, backend="jnp",
+                                   **kw)):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      np.asarray(want).view(np.uint32))
+
+
+def test_in_kernel_draw_is_jax_random_uniform():
+    """kernel.threefry_uniform(fold_in(key, b), c) is element c of
+    jax.random.uniform(fold_in(key, b), (pack, rows, 512)) bit for bit —
+    at the first and last counter of a 4Mi-element bucket, and across a
+    bucket boundary of the flat buffer."""
+    from repro.kernels.quant import kernel
+
+    assert jax.config.jax_threefry_partitionable   # the formula needs it
+
+    def drawn(keys, b, c):
+        k = keys[b]
+        return np.asarray(kernel.threefry_uniform(
+            k[..., 0], k[..., 1], jnp.asarray(c, jnp.int32))).view(np.uint32)
+
+    cap = 1 << 22
+    ends = np.array([0, 1, 511, 512, 1 << 21, cap - 2, cap - 1])
+    for seed in (0, 7, 2 ** 31 + 5):
+        key = jax.random.PRNGKey(seed)
+        keys = q_ops.bucket_keys(key, 3)
+        for bits in (8, 4, 2):
+            pack = 8 // bits
+            u = jax.random.uniform(q_ops.bucket_key(key, 2),
+                                   (pack, cap // (pack * 512), 512),
+                                   jnp.float32)
+            np.testing.assert_array_equal(
+                drawn(keys, np.full(ends.shape, 2), ends),
+                np.asarray(u).reshape(-1)[ends].view(np.uint32))
+        p = np.arange(cap - 64, cap + 64)       # bucket 0 -> bucket 1
+        want = np.concatenate([
+            np.asarray(jax.random.uniform(q_ops.bucket_key(key, b),
+                                          (1, cap // 512, 512)))
+            .reshape(-1)[sl] for b, sl in ((0, slice(-64, None)),
+                                            (1, slice(0, 64)))])
+        np.testing.assert_array_equal(drawn(keys, p // cap, p % cap),
+                                      want.view(np.uint32))
+
+
+def test_bucket_keys_refuse_draws_the_kernel_cannot_match():
+    """Off jax_threefry_partitionable, jax.random.uniform draws other bits
+    than the kernel's formula: the Pallas path refuses to run."""
+    was = jax.config.jax_threefry_partitionable
+    jax.config.update("jax_threefry_partitionable", False)
+    try:
+        with pytest.raises(ValueError, match="partitionable"):
+            q_ops.bucket_keys(KEY, 2)
+    finally:
+        jax.config.update("jax_threefry_partitionable", was)
+
+
 # -------------------------------------------------------------- wire bytes ---
 
 @pytest.mark.parametrize("name,bits", [("rq8", 8), ("rq4", 4), ("rq2", 2)])

@@ -6,7 +6,9 @@ accepts and tiles that fit VMEM. The topology is described in a fixture,
 only once a test of this file runs, and the tests skip where it cannot be
 described.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,15 +61,45 @@ def _shape(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _instructions(hlo: str):
+    """(opcode, dtype, elements, op_name) of every HLO instruction, fused
+    computations' included (a Mosaic kernel's body is not HLO)."""
+    pat = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?(\w+)\[([\d,]*)\]"
+                     r"\S* ([\w\-]+)\(")
+    for line in hlo.splitlines():
+        m = pat.match(line)
+        if m:
+            dtype, dims, opcode = m.groups()
+            name = re.search(r'op_name="([^"]*)"', line)
+            yield (opcode, dtype, math.prod(int(d) for d in dims.split(",")
+                                            if d), name.group(1) if name
+                   else "")
+
+
 @pytest.mark.parametrize("bits", [8, 4, 2])
 def test_qdq_flat_compiles_at_repro_100m_gradient(one_chip, compiled_for_tpu,
                                                   bits):
+    """The compiled codec is its two kernels over the unpadded buffer: no
+    uniforms drawn outside them (the only threefry work left is the
+    per-bucket key fold-ins) and no pad, update, copy or concatenation
+    that writes a buffer of the gradient's size."""
     flat = _shape(one_chip, (REPRO_100M_GRAD,), jnp.float32)
     key = _shape(one_chip, (2,), jnp.uint32)
     hlo = compiled_for_tpu(
         lambda f, k: quant_ops.qdq_flat(f, k, bits=bits, backend="pallas"),
         flat, key)
-    assert "tpu_custom_call" in hlo
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    _, _, nb, _, _ = quant_ops.flat_geometry(REPRO_100M_GRAD, bits=bits)
+    ops = list(_instructions(hlo))
+    assert not [o for o in ops if o[0].startswith("rng")]
+    draws = [o for o in ops if re.search(r"threefry|uniform|random_bits",
+                                         o[3])]
+    assert draws and all(o[2] <= 2 * nb and "uniform" not in o[3]
+                         for o in draws), draws
+    assert not [o for o in ops
+                if o[0] in ("pad", "dynamic-update-slice", "copy",
+                            "concatenate")
+                and o[1] == "f32" and o[2] >= REPRO_100M_GRAD]
 
 
 def test_ring_hop_compiles_at_repro_100m_partition(one_chip,
