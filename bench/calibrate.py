@@ -152,7 +152,6 @@ def look(cell, devices, args) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from bench.reference import train as ref_train
     drv = cell.driver()
     mix = cell.traffic
     b1, codec = mix["optimizer"]["b1"], mix["codec"]

@@ -22,9 +22,9 @@ import math
 import time
 
 from bench.generators import open_loop
-from bench.lib import compare, program, stats
+from bench.lib import compare, stats
+from bench.lib import program  # noqa: F401  (puts the program on sys.path)
 from bench.lib.seeds import jax_key, np_rng
-from bench.reference import decoder, weights as W
 
 WEIGHTS_TAG, ENGINE_TAG, CHECK_TAG = 0, 4, 5
 
@@ -41,14 +41,13 @@ class Rec:
         self.failed = False
 
 
-def build_engine(ctx):
+def build_engine(ctx, mc):
     import jax
     from repro import serve
     from repro.models import transformer_scan
-    cfg, mix = ctx.config, ctx.mix
-    mc = program.model_config(cfg)
+    cfg, mix, fam = ctx.config, ctx.mix, ctx.family
     k_w = jax_key(ctx.seed, WEIGHTS_TAG)
-    params = jax.jit(lambda k: W.pack_scanned(W.make(cfg, k)))(k_w)
+    params = jax.jit(lambda k: fam.pack_scanned(fam.make(cfg, k)))(k_w)
     jax.block_until_ready(params)
     theirs = jax.eval_shape(lambda k: transformer_scan.init(mc, k), k_w)
     if jax.tree.structure(theirs) != jax.tree.structure(params):
@@ -92,12 +91,12 @@ def measure(ctx):
     is freed before this returns."""
     from repro import serve
 
-    cfg, mix = ctx.config, ctx.mix
-    m = W.dims(cfg)
+    mix = ctx.mix
+    mc = ctx.family.program_config(ctx.config)
     ctx.mark("imports and devices")
-    engine = build_engine(ctx)
+    engine = build_engine(ctx, mc)
     ctx.mark("weights and engine")
-    sched = open_loop.schedule(mix, m["v"], ctx.seed, ctx.seconds)
+    sched = open_loop.schedule(mix, mc.vocab, ctx.seed, ctx.seconds)
     warm_up(engine, [len(r.prompt) for r in sched])
     ctx.mark("warm-up: compile or cache load, one request per length")
 
@@ -225,7 +224,7 @@ def pick_sample(ctx, counted) -> list:
     return picked
 
 
-def gap_fn(cfg: dict, max_len: int, control: str | None = None):
+def gap_fn(fam, cfg: dict, max_len: int, control: str | None = None):
     """Jitted (weights, seq, pos, tok, mask) -> widest gap below the
     float32 reference's best logit: of the served tokens, or (with
     ``control``) of the tokens a lower precision puts first."""
@@ -233,11 +232,11 @@ def gap_fn(cfg: dict, max_len: int, control: str | None = None):
     import jax.numpy as jnp
 
     def fn(canon, seq, pos, tok, mask):
-        z = decoder.logits(cfg, canon, seq[None])[0][pos]      # (N, V)
+        z = fam.logits(cfg, canon, seq[None])[0][pos]          # (N, V)
         best = jnp.max(z, -1)
         if control is not None:
-            zc = decoder.logits(cfg, canon, seq[None],
-                                precision=control)[0][pos]
+            zc = fam.logits(cfg, canon, seq[None],
+                            precision=control)[0][pos]
             tok = jnp.argmax(zc, -1)
         g = best - jnp.take_along_axis(z, tok[:, None], 1)[:, 0]
         return jnp.max(jnp.where(mask, g, -jnp.inf))
@@ -252,10 +251,10 @@ def served_gap(ctx, sample, control: str | None = None) -> float:
     import numpy as np
     if not sample:
         return math.inf
-    cfg, L = ctx.config, ctx.mix["max_len"]
-    canon = jax.jit(lambda k: W.make(cfg, k))(jax_key(ctx.seed,
-                                                      WEIGHTS_TAG))
-    fn = gap_fn(cfg, L, control)
+    cfg, fam, L = ctx.config, ctx.family, ctx.mix["max_len"]
+    canon = jax.jit(lambda k: fam.make(cfg, k))(jax_key(ctx.seed,
+                                                        WEIGHTS_TAG))
+    fn = gap_fn(fam, cfg, L, control)
     worst = -math.inf
     with jax.default_matmul_precision("highest"):
         for prompt, tokens in sample:

@@ -16,9 +16,10 @@ import math
 import time
 
 from bench.generators import lm_batches
-from bench.lib import compare, program
+from bench.lib import compare
+from bench.lib import program  # noqa: F401  (puts the program on sys.path)
 from bench.lib.seeds import jax_key
-from bench.reference import train as ref_train, weights as W
+from bench.reference import train as ref_train
 
 WEIGHTS_TAG, RNG_TAG = 0, 1
 
@@ -42,8 +43,8 @@ class Loop:
         from repro.launch import mesh as mesh_lib
         from repro.train import steps
 
-        self.ctx, cfg, mix = ctx, ctx.config, ctx.mix
-        self.mc = program.model_config(cfg)
+        self.ctx, cfg, mix, fam = ctx, ctx.config, ctx.mix, ctx.family
+        self.mc = fam.program_config(cfg)
         self.mesh = mesh_lib.make_mesh(tuple(mix["mesh"]), ("data", "model"),
                                        devices=ctx.devices)
         sharding.set_activation_batch_axes(("data",))
@@ -63,7 +64,7 @@ class Loop:
             def build(kw, kr):
                 st = steps.init_train_state(self.mc, self.opt, kr,
                                             step_cfg=scfg)
-                ours = W.pack_unrolled(W.make(cfg, kw))
+                ours = fam.pack_unrolled(fam.make(cfg, kw))
                 if (jax.tree.structure(ours)
                         != jax.tree.structure(st["params"])):
                     raise ValueError("the program's parameter layout is not "
@@ -107,7 +108,7 @@ def first_steps(loop, on_first=None) -> tuple:
     first moment after one step) and of the parameters' change since
     set-up. ``on_first(loop)``, where given, reads more after step 1."""
     import jax
-    cfg, mix = loop.ctx.config, loop.ctx.mix
+    cfg, mix, fam = loop.ctx.config, loop.ctx.mix, loop.ctx.family
     b1 = mix["optimizer"]["b1"]
     norms = jax.jit(ref_train.leaf_norms)
     losses, grad1 = [], None
@@ -119,7 +120,8 @@ def first_steps(loop, on_first=None) -> tuple:
             if on_first is not None:
                 on_first(loop)
     change_fn = jax.jit(lambda p, kw: ref_train.leaf_norms(
-        jax.tree.map(lambda a, b: a - b, p, W.pack_unrolled(W.make(cfg, kw)))))
+        jax.tree.map(lambda a, b: a - b, p,
+                     fam.pack_unrolled(fam.make(cfg, kw)))))
     change = {k: float(v) for k, v in
               change_fn(loop.state["params"], loop.k_weights).items()}
     return losses, grad1, change
@@ -189,12 +191,12 @@ def reference(ctx, batches, *, precision: str = "float32", rows=None,
     first batches: in float32 (the yardstick), in a lower precision (the
     control) or with ``rows`` only (a planted fault)."""
     import jax
-    canon = jax.jit(lambda k: W.make(ctx.config, k))(
+    canon = jax.jit(lambda k: ctx.family.make(ctx.config, k))(
         jax_key(ctx.seed, WEIGHTS_TAG))
     k_rng = jax_key(ctx.seed, RNG_TAG)
     keys = [jax.random.fold_in(k_rng, t) for t in range(len(batches))]
-    return ref_train.run(ctx.config, ctx.mix, canon, batches, keys,
-                         precision=precision, rows=rows,
+    return ref_train.run(ctx.family, ctx.config, ctx.mix, canon, batches,
+                         keys, precision=precision, rows=rows,
                          n_steps=len(batches), keep_message=keep_message)
 
 

@@ -1,5 +1,6 @@
-"""The program under test, as the benchmark builds it from a
-configuration file: its model configuration and its parameter layouts."""
+"""The program under test, as the benchmark reaches it: its sources on
+``sys.path`` (importing this module puts them there) and its compile
+cache."""
 from __future__ import annotations
 
 import sys
@@ -8,22 +9,6 @@ from bench.lib.spec import ROOT
 
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
-
-
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` of a decoder config file."""
-    from repro.models.common import ModelConfig
-    from bench.reference.weights import dims
-    if cfg.get("hidden_act") != "silu" or not cfg.get("tie_word_embeddings"):
-        raise ValueError("only SwiGLU decoders with tied embeddings are "
-                         "described by these config files")
-    m = dims(cfg)
-    return ModelConfig(
-        arch_id=cfg["program_arch"], family="dense", n_layers=m["L"],
-        d_model=m["d"], n_heads=m["h"], n_kv_heads=m["hk"], d_ff=m["f"],
-        vocab=m["v"], head_dim=m["hd"], qkv_bias=m["bias"],
-        rope_theta=m["theta"], norm="rmsnorm", norm_eps=m["eps"],
-        act="silu", glu=True, tie_embeddings=True)
 
 
 def use_compile_cache() -> str:

@@ -67,13 +67,14 @@ class Tracer:
 
 
 class Ctx:
-    """What a driver gets: the cell's files, the run's arguments, the
-    devices, and a way to report."""
+    """What a cell's ``run`` gets: the cell's files and model family, the
+    run's arguments, the devices, and a way to report."""
 
     def __init__(self, cell, *, seed, seconds, trace, devices, t_process):
         self.workload = cell.name
         self.config, self.mix, self.limits = cell.config, cell.traffic, \
             cell.limits
+        self.family = cell.family()
         self.seed, self.seconds, self.trace = seed, seconds, trace
         self.devices, self.t_process = devices, t_process
         self._tracer = None
@@ -114,9 +115,9 @@ class Ctx:
 class Reading:
     """What a per-layer metric's ``read`` gets."""
 
-    def __init__(self, trace, facts, peaks, config, mix):
+    def __init__(self, trace, facts, peaks, config, mix, family):
         self.trace, self.facts, self.peaks = trace, facts, peaks
-        self.config, self.mix = config, mix
+        self.config, self.mix, self.family = config, mix, family
 
 
 def device_info(devices, memory_peak) -> dict:
@@ -144,7 +145,8 @@ def run_cell(cell, *, seed, seconds, trace, devices, t_process) -> dict:
         data = trace_lib.load(tr.dir, n_devices=len(devices),
                               hlo_text=out["facts"].get("hlo_text", ""))
         shutil.rmtree(tr.dir, ignore_errors=True)
-        reading = Reading(data, out["facts"], peaks, cell.config, cell.traffic)
+        reading = Reading(data, out["facts"], peaks, cell.config, cell.traffic,
+                          ctx.family)
         metrics = {}
         readers = cell.metric_readers()
         for m in cell.per_layer:

@@ -1,10 +1,12 @@
 """Find everything of a cell by the names in ``BENCHMARK.json``.
 
-A configuration is ``configs/<config>.json`` (the entry's ``file``), a
+A configuration is ``configs/<config>.json`` (the entry's ``file``), its
+model family ``models/<family>.py`` (named by the configuration), a
 traffic mix ``traffic/<traffic>.json``, a driver ``drivers/<driver>.py``
 (named by the mix), the limits of a cell's correctness check
 ``limits/<workload>.json`` and a per-layer metric ``metrics/<metric>.py``.
-Adding a cell, a mix or a metric adds files; no file here changes.
+Adding a configuration, a family, a cell, a mix or a metric adds files; no
+file here changes.
 """
 from __future__ import annotations
 
@@ -40,6 +42,16 @@ def load_module(path: Path, name: str):
     return mod
 
 
+def family(cfg: dict, *, root: Path = ROOT):
+    """The model family module ``bench/models/<family>.py`` that the
+    configuration names by its ``"family"`` key."""
+    if "family" not in cfg:
+        raise SpecError('the configuration names no "family"')
+    name = cfg["family"]
+    return load_module(Path(root) / "bench" / "models" / f"{name}.py",
+                       f"bench_family_{name}")
+
+
 class Cell:
     """One workload of BENCHMARK.json with its files loaded."""
 
@@ -70,6 +82,9 @@ class Cell:
         kind = self.traffic["driver"]
         return load_module(self.dir / "drivers" / f"{kind}.py",
                            f"bench_driver_{kind}")
+
+    def family(self):
+        return family(self.config, root=self.root)
 
     def metric_readers(self) -> dict:
         """name -> module with ``read(ctx) -> float | None``."""

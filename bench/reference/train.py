@@ -1,9 +1,10 @@
-"""Reference of the first training steps: loss and gradient of the plain
-decoder, global-norm clipping, the gradient message through randomized
-quantization with error feedback, and AdamW under a warm-up-then-cosine
-learning rate. Works on the benchmark's canonical weights; the gradient
-message is laid out as the unrolled per-layer tree flattened in JAX's
-key order, which is the order of the wire message."""
+"""Reference of the first training steps: loss and gradient of the model
+family's plain reference, global-norm clipping, the gradient message
+through randomized quantization with error feedback, and AdamW under a
+warm-up-then-cosine learning rate. Works on the family's canonical
+weights; the gradient message is laid out as the family's unrolled
+per-layer tree flattened in JAX's key order, which is the order of the
+wire message."""
 from __future__ import annotations
 
 import math
@@ -12,7 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import decoder, rq, weights as W
+from bench.reference import rq
+from bench.reference.ops import cast
 
 
 def lr_at(opt: dict, step):
@@ -33,24 +35,24 @@ def leaf_norms(tree) -> dict:
         x.astype(jnp.float32)))) for p, x in flat}
 
 
-def make_step(cfg: dict, mix: dict, *, precision: str = "float32",
+def make_step(fam, cfg: dict, mix: dict, *, precision: str = "float32",
               rows=None):
-    """One reference step as a jitted function
+    """One reference step of the model family ``fam`` as a jitted function
     (canon, m, v, err, step, tokens, labels, key) -> (loss, new canon,
     m, v, err, quantized gradient in the unrolled layout).
 
     ``rows`` (a slice) restricts the loss to some rows of the batch: a
     planted fault, a batch half left out or one chip's rows alone."""
     opt, codec = mix["optimizer"], mix["codec"]
-    dt = decoder._cast(precision)
+    dt = cast(precision)
 
     def step_fn(canon, m, v, err, step, tokens, labels, qkey):
         if rows is not None:
             tokens, labels = tokens[rows], labels[rows]
         loss, g = jax.value_and_grad(
-            lambda c: decoder.loss(cfg, c, tokens, labels,
-                                   precision=precision))(canon)
-        tree = W.pack_unrolled(g)
+            lambda c: fam.loss(cfg, c, tokens, labels,
+                               precision=precision))(canon)
+        tree = fam.pack_unrolled(g)
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         gn = jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
                           for x in leaves))
@@ -68,7 +70,7 @@ def make_step(cfg: dict, mix: dict, *, precision: str = "float32",
         gq_tree = jax.tree_util.tree_unflatten(treedef, [
             x.reshape(l.shape) for x, l in zip(jnp.split(flat, sizes),
                                               leaves)])
-        gq = W.unpack_unrolled(gq_tree)
+        gq = fam.unpack_unrolled(gq_tree)
         t = step + 1
         b1, b2, eps = opt["b1"], opt["b2"], opt["eps"]
         eta = lr_at(opt, t)
@@ -88,7 +90,7 @@ def make_step(cfg: dict, mix: dict, *, precision: str = "float32",
     return jax.jit(step_fn, donate_argnums=(0, 1, 2, 3))
 
 
-def run(cfg: dict, mix: dict, canon: dict, batches, keys, *,
+def run(fam, cfg: dict, mix: dict, canon: dict, batches, keys, *,
         precision: str = "float32", rows=None, n_steps: int = 3,
         keep_message: bool = False) -> dict:
     """Follow ``n_steps`` steps from ``canon``; return the readings:
@@ -96,8 +98,8 @@ def run(cfg: dict, mix: dict, canon: dict, batches, keys, *,
     and per-leaf norms of the parameters' change after the last step.
     ``keep_message`` adds the first gradient message itself, on the host,
     flat in the order of the wire message."""
-    dt = decoder._cast(precision)
-    step = make_step(cfg, mix, precision=precision, rows=rows)
+    dt = cast(precision)
+    step = make_step(fam, cfg, mix, precision=precision, rows=rows)
     c = {k: jnp.array(x, dtype=dt, copy=True) for k, x in canon.items()}
     m = {k: jnp.zeros_like(x) for k, x in c.items()}
     v = {k: jnp.zeros_like(x) for k, x in c.items()}
@@ -113,8 +115,8 @@ def run(cfg: dict, mix: dict, canon: dict, batches, keys, *,
             if keep_message:
                 msg1 = np.concatenate([np.asarray(x).reshape(-1) for x in
                                        jax.tree_util.tree_leaves(gq)])
-    delta = W.pack_unrolled({k: c[k].astype(jnp.float32) - canon[k]
-                             for k in c})
+    delta = fam.pack_unrolled({k: c[k].astype(jnp.float32) - canon[k]
+                               for k in c})
     out = {"losses": losses, "grad1": g1,
            "change": {k: float(x) for k, x in leaf_norms(delta).items()}}
     if keep_message:

@@ -12,10 +12,6 @@ if ROOT not in sys.path:
 
 import pytest  # noqa: E402
 
-TINY_MODEL = dict(hidden_size=64, intermediate_size=128,
-                  num_attention_heads=4, head_dim=16, num_hidden_layers=2,
-                  vocab_size=256)
-
 # The serving driver's parameters at a size a test run holds. No serving
 # cell is in BENCHMARK.json; the tests add one as a later cell would be
 # added, by a traffic file, a limits file and entries.
@@ -61,14 +57,12 @@ def serve_cell(root):
 
 def tiny_cell(cell):
     """A cell (or the cell of BENCHMARK.json named ``cell``) at a size a
-    test run holds: every width and count cut, the mix's shape kept."""
+    test run holds: the configuration cut by its family's ``tiny``, the
+    mix's shape kept."""
     from bench.lib.spec import Cell
     if isinstance(cell, str):
         cell = Cell(cell)
-    cell.config = dict(cell.config, **TINY_MODEL,
-                       num_key_value_heads=2 if cell.config[
-                           "num_key_value_heads"] < cell.config[
-                           "num_attention_heads"] else 4)
+    cell.config = cell.family().tiny(cell.config)
     if cell.traffic["driver"] == "train":
         cell.traffic = dict(cell.traffic, batch=8, seq_len=32,
                             distinct_batches=4, trace_seconds=0.5)

@@ -1,13 +1,12 @@
-"""FLOP and byte counts of the per-layer metrics, against the sizes of
-the configurations worked out by hand."""
+"""FLOP and byte counts of the per-layer metrics, as the configurations'
+families give them, against the sizes worked out by hand."""
 import json
 import types
 
 import pytest
 
-from bench.lib import flops
 from bench.lib.peaks import PEAKS, peaks_for
-from bench.lib.spec import BENCH, load_module
+from bench.lib.spec import BENCH, family, load_module
 
 
 def _cfg(name):
@@ -22,13 +21,15 @@ def _metric(name):
 
 def test_repro_100m_sizes():
     cfg = _cfg("repro-100m")
-    assert flops.n_params(cfg) == 128_994_048
-    assert flops.matmul_params(cfg) == 128_974_848
+    fam = family(cfg)
+    assert fam.n_params(cfg) == 128_994_048
+    # with no attention, 6 FLOPs per matrix-product weight
+    assert fam.model_flops_per_token(cfg, 0) == 6 * 128_974_848
 
 
 def test_repro_100m_model_flops_per_token_and_step():
-    mfu = _metric("mfu.train")
-    f = mfu.flops_per_token(_cfg("repro-100m"), 1024)
+    cfg = _cfg("repro-100m")
+    f = family(cfg).model_flops_per_token(cfg, 1024)
     # 6 x 128,974,848 + 12 * L * H * d_head * S
     assert f == 6 * 128_974_848 + 12 * 12 * 12 * 64 * 1024 == 887_095_296
     assert 8 * 1024 * f == pytest.approx(7.267e12, rel=1e-3)
@@ -36,16 +37,18 @@ def test_repro_100m_model_flops_per_token_and_step():
 
 def test_codec_interface_bytes():
     roof = _metric("codec_roofline.train")
-    assert roof.interface_bytes(_cfg("repro-100m")) == 8 * 128_994_048
+    cfg = _cfg("repro-100m")
+    assert roof.interface_bytes(family(cfg), cfg) == 8 * 128_994_048
 
 
 def test_qwen_sizes_and_decode_bytes():
     cfg = _cfg("qwen1.5-0.5b")
-    assert flops.n_params(cfg) == 463_987_712
-    assert flops.kv_bytes_per_position(cfg, 2) == 2 * 24 * 16 * 64 * 2
+    fam = family(cfg)
+    assert fam.n_params(cfg) == 463_987_712
+    assert fam.kv_bytes_per_position(cfg, 2) == 2 * 24 * 16 * 64 * 2
     dec = _metric("decode_hbm_roofline.serve")
-    assert dec.least_bytes(cfg, 3, 1000) == (3 * 463_987_712 * 2
-                                             + 1000 * 98_304)
+    assert dec.least_bytes(fam, cfg, 3, 1000) == (3 * 463_987_712 * 2
+                                                  + 1000 * 98_304)
 
 
 def test_peaks_table_has_sources_and_refuses_unknown_devices():
@@ -64,10 +67,10 @@ def _reading(codec_s_per_step=None):
         op_seconds=lambda pred: 10 * codec_s_per_step)
     trace = types.SimpleNamespace(devices=[dev], scope=lambda n: n,
                                   window_s=lambda: 1.1)
+    cfg = _cfg("repro-100m")
     return types.SimpleNamespace(
-        trace=trace, facts={"chips": 1},
-        peaks=PEAKS["TPU v5 lite"], config=_cfg("repro-100m"),
-        mix={"batch": 8, "seq_len": 1024})
+        trace=trace, facts={"chips": 1}, peaks=PEAKS["TPU v5 lite"],
+        config=cfg, mix={"batch": 8, "seq_len": 1024}, family=family(cfg))
 
 
 def test_metric_readers_arithmetic():

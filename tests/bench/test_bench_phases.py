@@ -57,7 +57,8 @@ def _trace(devices=None, scopes=SCOPES):
 
 def _reading(trace, hlo=HLO):
     cell = Cell(CELLS[1])
-    return Reading(trace, {"hlo_text": hlo}, {}, cell.config, cell.traffic)
+    return Reading(trace, {"hlo_text": hlo}, {}, cell.config, cell.traffic,
+                   cell.family())
 
 
 @pytest.mark.parametrize("name,cls", [

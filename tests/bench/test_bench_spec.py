@@ -8,7 +8,7 @@ import sys
 
 import pytest
 
-from bench.lib.spec import BENCH, ROOT, Cell
+from bench.lib.spec import BENCH, ROOT, Cell, SpecError
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -75,6 +75,25 @@ def test_a_new_traffic_file_is_found_by_name_alone(tmp_path, bench):
     assert cell.traffic["codec"]["name"] == "none"
     assert cell.config["hidden_size"] == 768
     assert cell.driver().first_steps
+
+
+@pytest.mark.parametrize("family", [None, "no-such-family"])
+def test_a_configuration_names_a_family_that_exists(tmp_path, family):
+    """A configuration without ``"family"``, or naming a family with no
+    module, is refused; there is no default."""
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    path = tmp_path / "bench" / "configs" / "repro-100m.json"
+    cfg = json.loads(path.read_text())
+    if family is None:
+        del cfg["family"]
+    else:
+        cfg["family"] = family
+    path.write_text(json.dumps(cfg))
+    cell = Cell("train.repro-100m.rq8ef", root=tmp_path)
+    with pytest.raises(SpecError):
+        cell.family()
 
 
 def _run_bench(cwd, *extra, env_platform="cpu"):
